@@ -9,12 +9,10 @@ fixed-point, reporting queries/s and wave latency per shard count.
 
     PYTHONPATH=src python benchmarks/bench_sharded_serving.py [--scale 0.02] [--dry-run]
 
-Per run-book, multi-device work runs in a subprocess with forced host devices
-so the invoking process keeps its single default device: ``main`` re-executes
-this file with ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` and
-parses the JSON rows the inner run prints.  (On a real multi-chip platform the
-forced-host-device flag is unnecessary — the inner run only forces it when the
-visible device count is short.)
+Everything runs in the calling process, which must see the devices: on the
+chip every shard count up to the visible device count runs; with
+``JAX_PLATFORMS=cpu`` the entry points expose host devices
+(``repro.launch.mesh.cpu_host_devices``) before JAX starts.
 
 ``--dry-run`` is the CI smoke path (tiny graph, shards 1/2, one precision).
 Output is the house ``name,us_per_call,derived`` CSV.
@@ -22,24 +20,23 @@ Output is the house ``name,us_per_call,derived`` CSV.
 from __future__ import annotations
 
 import argparse
-import json
-import os
-import subprocess
-import sys
 from typing import Dict, List, Optional, Sequence
 
-_ROW_MARKER = "BENCH_SHARDED_ROWS:"
+import jax
+import numpy as np
+
+from repro.graphs import holme_kim_powerlaw
+from repro.launch.mesh import cpu_host_devices, make_mesh
+from repro.ppr_serving import PPRQuery, PPRService
 
 
-def _inner(scale: float, n_queries: int, kappa: int, iterations: int,
-           shards: Sequence[int], precisions: Sequence[Optional[int]],
-           seed: int = 0) -> List[Dict]:
-    """Runs with devices available; one PPRService per (shards, precision)."""
-    import jax
-    import numpy as np
-
-    from repro.graphs import holme_kim_powerlaw
-    from repro.ppr_serving import PPRQuery, PPRService
+def run(scale: float = 0.02, n_queries: int = 32, kappa: int = 8,
+        iterations: int = 10, shards: Sequence[int] = (1, 2, 4, 8),
+        precisions: Sequence[Optional[int]] = (None, 26),
+        seed: int = 0) -> List[Dict]:
+    """One PPRService per (shards, precision), for every shard count the
+    visible devices allow."""
+    shards = [n for n in shards if n <= jax.device_count()]
 
     # deliberately not a multiple of any shard count: the ceil-division padded
     # layout is the production case, so it is the benchmarked one
@@ -49,8 +46,7 @@ def _inner(scale: float, n_queries: int, kappa: int, iterations: int,
     users = rng.integers(0, g.num_vertices, n_queries)
     rows: List[Dict] = []
     for n_shards in shards:
-        mesh = None if n_shards == 1 else \
-            jax.make_mesh((n_shards,), ("shard",))
+        mesh = None if n_shards == 1 else make_mesh((n_shards,), ("shard",))
         for prec in precisions:
             svc = PPRService(kappa=kappa, iterations=iterations,
                              cache_capacity=0)       # measure compute, not cache
@@ -82,36 +78,6 @@ def _inner(scale: float, n_queries: int, kappa: int, iterations: int,
     return rows
 
 
-def run(scale: float = 0.02, n_queries: int = 32, kappa: int = 8,
-        iterations: int = 10, shards: Sequence[int] = (1, 2, 4, 8),
-        precisions: Sequence[Optional[int]] = (None, 26)) -> List[Dict]:
-    """Spawn the inner measurement with enough (forced) host devices."""
-    need = max(shards)
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in flags:
-        # append, preserving any operator-set flags (threading, determinism);
-        # an operator-forced device count is respected as-is
-        env["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={need}").strip()
-    src = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                       "src")
-    env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
-                               if env.get("PYTHONPATH") else "")
-    spec = json.dumps({"scale": scale, "n_queries": n_queries, "kappa": kappa,
-                       "iterations": iterations, "shards": list(shards),
-                       "precisions": list(precisions)})
-    out = subprocess.run([sys.executable, os.path.abspath(__file__),
-                          "--inner", spec],
-                         capture_output=True, text=True, env=env, timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(f"inner sharded bench failed:\n{out.stdout}\n{out.stderr}")
-    for line in out.stdout.splitlines():
-        if line.startswith(_ROW_MARKER):
-            return json.loads(line[len(_ROW_MARKER):])
-    raise RuntimeError(f"inner sharded bench produced no rows:\n{out.stdout}")
-
-
 def main(scale: float = 0.02, dry_run: bool = False) -> List[Dict]:
     if dry_run:
         rows = run(scale=0.005, n_queries=8, kappa=4, shards=(1, 2),
@@ -135,13 +101,6 @@ if __name__ == "__main__":
     ap.add_argument("--scale", type=float, default=0.02)
     ap.add_argument("--dry-run", action="store_true",
                     help="tiny graph, shards 1/2 — the CI smoke path")
-    ap.add_argument("--inner", metavar="JSON_SPEC", default=None,
-                    help=argparse.SUPPRESS)   # subprocess protocol, not a user flag
     args = ap.parse_args()
-    if args.inner is not None:
-        spec = json.loads(args.inner)
-        spec["precisions"] = [None if p is None else int(p)
-                              for p in spec["precisions"]]
-        print(_ROW_MARKER + json.dumps(_inner(**spec)))
-    else:
-        main(scale=args.scale, dry_run=args.dry_run)
+    cpu_host_devices(8)
+    main(scale=args.scale, dry_run=args.dry_run)

@@ -10,6 +10,11 @@ runs in ~2 minutes.
 per section ({"bench", "scale", "rows": [...]}) so the perf trajectory can be
 tracked across commits without re-parsing the human CSV.
 
+Every section runs in this one process (the chip belongs to one process at a
+time).  With ``JAX_PLATFORMS=cpu`` the harness exposes 8 host devices for the
+sharded section; the persistent compile cache is placed by
+``repro.launch.compile_cache.use_compile_cache``.
+
 ``--check`` (with ``--json``) verifies the baselines after the sweep: every
 section that ran must have written a parseable, non-empty file, and a section
 that was *skipped* must not leave a baseline behind — a silently-skipped
@@ -30,6 +35,8 @@ from benchmarks import (bench_accuracy, bench_autotune, bench_convergence,
                         bench_graph_updates, bench_ppr, bench_serving_http,
                         bench_serving_ppr, bench_sharded_serving, bench_spmv)
 from benchmarks import roofline_report
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import cpu_host_devices
 
 
 def _jsonable(o: Any):
@@ -68,6 +75,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.check and not args.json:
         ap.error("--check requires --json (it verifies the written baselines)")
+    cpu_host_devices(8)
+    use_compile_cache()
     scale = 1.0 if args.full else args.scale
     if args.dry_run:
         # sections without a native dry-run mode shrink through scale alone

@@ -8,6 +8,8 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# CI runs on the CPU: Pallas kernels interpreted, host devices for meshes
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
 echo "== tier-1: pytest =="
 python -m pytest -x -q

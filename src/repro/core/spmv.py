@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.fixed_point import QFormat
 
 Array = jax.Array
@@ -71,7 +70,7 @@ def spmv_fixed(
 # ----------------------------------------------------------------------------
 # 3. Pallas kernel path (imported lazily to keep core importable sans kernels)
 # ----------------------------------------------------------------------------
-def spmv_pallas(blocked, p: Array, *, interpret: bool = True) -> Array:
+def spmv_pallas(blocked, p: Array, *, interpret: Optional[bool] = None) -> Array:
     from repro.kernels import ops as kops
 
     return kops.coo_spmv(blocked, p, interpret=interpret)
@@ -110,7 +109,7 @@ def make_sharded_spmv(mesh, axis: str, num_vertices: int):
         contrib = val[:, None] * p[y]
         return jax.ops.segment_sum(contrib, x_loc, num_segments=v_local)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_spmv,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P()),
@@ -141,7 +140,7 @@ def make_sharded_spmv_fixed(mesh, axis: str, num_vertices: int, fmt: QFormat):
                                   num_segments=v_local)
         return acc.astype(jnp.uint32)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local_spmv,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P()),

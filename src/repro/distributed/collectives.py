@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.quantization import truncate_to_grid
 
 
@@ -55,7 +54,7 @@ def make_compressed_grad_allreduce(mesh: Mesh, axis: str, frac_bits: int = 12):
     def wrapped(grads, residuals):
         specs = jax.tree.map(lambda _: P(axis), grads)  # grads sharded on data
         rspecs = jax.tree.map(lambda _: P(axis), residuals)
-        return shard_map(
+        return jax.shard_map(
             allreduce, mesh=mesh,
             in_specs=(specs, rspecs),
             out_specs=(jax.tree.map(lambda _: P(axis), grads), rspecs),

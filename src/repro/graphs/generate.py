@@ -9,7 +9,7 @@ cross-check — pure-python generation of 2e6 edges is too slow for benchmarks).
 """
 from __future__ import annotations
 
-from typing import Dict, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -111,25 +111,28 @@ def load_snap_edgelist(path: str, num_vertices: int | None = None) -> COOGraph:
     return COOGraph.from_edges(src, dst, n)
 
 
-def paper_graph_suite(scale: float = 1.0, seed: int = 0) -> Dict[str, COOGraph]:
+def paper_graph_suite(scale: float = 1.0, seed: int = 0,
+                      names: Optional[Sequence[str]] = None) -> Dict[str, COOGraph]:
     """The paper's Table 1 synthetic suite, optionally scaled down for CI.
 
     scale=1.0 reproduces |V|∈{1e5, 2e5}, |E|≈{1e6, 2e6}.  The two SNAP graphs are
-    substituted by statistically matched synthetics when the raw files are absent
-    (documented in DESIGN.md §9): amazon-like (powerlaw, |V|=128000, |E|≈443378)
-    and twitter-like (dense powerlaw, |V|=81306, |E|≈1572670).
+    substituted by statistically matched synthetics when the raw files are absent:
+    amazon-like (powerlaw, |V|=128000, |E|≈443378) and twitter-like (dense
+    powerlaw, |V|=81306, |E|≈1572670).  ``names`` generates only those graphs
+    (each is the same graph the full suite holds under its name).
     """
     v1 = max(64, int(1e5 * scale))
     v2 = max(128, int(2e5 * scale))
-    suite = {
-        "gnp_1e5": erdos_renyi(v1, max(32, int(1e6 * scale)), seed),
-        "gnp_2e5": erdos_renyi(v2, max(64, int(2e6 * scale)), seed + 1),
-        "ws_1e5": watts_strogatz(v1, k=20, seed=seed + 2),
-        "ws_2e5": watts_strogatz(v2, k=20, seed=seed + 3),
-        "pl_1e5": holme_kim_powerlaw(v1, m=10, seed=seed + 4),
-        "pl_2e5": holme_kim_powerlaw(v2, m=10, seed=seed + 5),
-        "amazon_like": holme_kim_powerlaw(max(64, int(128000 * scale)), m=3, seed=seed + 6),
-        "twitter_like": holme_kim_powerlaw(max(64, int(81306 * scale)), m=19,
-                                           p_triad=0.3, seed=seed + 7),
+    makers = {
+        "gnp_1e5": lambda: erdos_renyi(v1, max(32, int(1e6 * scale)), seed),
+        "gnp_2e5": lambda: erdos_renyi(v2, max(64, int(2e6 * scale)), seed + 1),
+        "ws_1e5": lambda: watts_strogatz(v1, k=20, seed=seed + 2),
+        "ws_2e5": lambda: watts_strogatz(v2, k=20, seed=seed + 3),
+        "pl_1e5": lambda: holme_kim_powerlaw(v1, m=10, seed=seed + 4),
+        "pl_2e5": lambda: holme_kim_powerlaw(v2, m=10, seed=seed + 5),
+        "amazon_like": lambda: holme_kim_powerlaw(
+            max(64, int(128000 * scale)), m=3, seed=seed + 6),
+        "twitter_like": lambda: holme_kim_powerlaw(
+            max(64, int(81306 * scale)), m=19, p_triad=0.3, seed=seed + 7),
     }
-    return suite
+    return {name: makers[name]() for name in (names or makers)}

@@ -1,38 +1,38 @@
-"""Fused Pallas PPR iteration: SpMV + eq. (1) axpy + dangling fold, one launch.
+"""Fused Pallas PPR iteration: SpMV + eq. (1) axpy + residual in one pass.
 
 The paper's core claim is a *streaming fused* pipeline (§4.1): SpMV, the
 eq. (1) axpy and the dangling-mass fold execute as one pass over the edge
 stream.  ``coo_spmv.py`` maps the §4.1.1 SpMV stage alone; this module fuses
-the whole iteration
+the iteration
 
     P_{t+1} = α·X·P_t + α/|V|·(d̄ᵀP_t)·1 + (1−α)·V̄        (eq. 1)
 
-into a single ``pallas_call`` so a serving wave pays one kernel launch per
-iteration instead of the composed jax-ops dispatch chain.  The grid is
+into one pass over the dst-major packetized edge stream.  The dangling mass
+d̄ᵀP_t is reduced first, with the same op as the composed step (for fixed
+point an int32 sum, exact mod 2^32), and fed to every launch as a [1, K]
+operand.  Then one grid step per edge packet runs the one-hot-MXU SpMV
+accumulation of ``coo_spmv.py``.  On the *last* packet of each dst block the
+kernel applies the eq. (1) combine in place — for fixed point, the exact
+``_fixed_combine`` nesting of truncating limb multiplies and saturating adds,
+so results are bit-identical (raw uint32) to the composed
+``make_ppr_fixed_step`` datapath — and folds |ΔP| into a [3, K] residual
+output (L1 / ∞ / Σd² per column) for the early-exit driver, replacing the
+separate host-synced reductions of ``ConvergenceMonitor``.
 
-    [ n_blk dangling-fold steps | dst-major packet stream steps ]
-
-- **Prologue** (one step per vertex block): accumulate d̄ᵀP into a [1, K]
-  dangling-mass output whose constant index map keeps it VMEM-resident for
-  the whole grid (Pallas output revisiting — it is written to HBM once, at
-  grid end).  Raw uint32 products are summed in int32, so the partial-sums-
-  per-block order is bit-identical (mod 2^32) to ``_fixed_dangling_mass``.
-- **Stream** (one step per edge packet, dst-major): the one-hot-MXU SpMV
-  accumulation of ``coo_spmv.py``.  On the *last* packet of each dst block
-  the kernel applies the eq. (1) combine in place — for fixed point, the
-  exact ``_fixed_combine`` nesting of truncating limb multiplies and
-  saturating adds, so results are bit-identical (raw uint32) to the composed
-  ``make_ppr_fixed_step`` datapath — and folds |ΔP| into a [3, K] residual
-  output (L1 / ∞ / Σd² per column) for the early-exit driver, replacing the
-  separate host-synced reductions of ``ConvergenceMonitor``.
+The schedule of one iteration is cut on whole dst blocks into launches whose
+scalar-prefetch operands fit SMEM (``coo_spmv.dst_chunks``).  Each launch
+writes its own dst blocks; the outputs concatenate and the per-launch
+residuals combine exactly (L1 and Σd² add, ∞ takes the max).
 
 Empty dst blocks get a sentinel step over a shared all-zero edge row so every
 output block is still zeroed + combined (a vertex with no in-edges keeps its
 (1−α)·V̄ + dangling terms).  Pad rows of the trailing ragged block are masked
 to zero after the combine, so the next iteration's pads stay zero.
 
-``interpret=True`` (the default off-TPU) runs the same kernel through the
-Pallas interpreter — slow, but bit-exact, which keeps CPU-only CI meaningful.
+``interpret=True`` runs the same kernel through the Pallas interpreter —
+slow, but bit-exact, which keeps CPU-only CI meaningful.
+``default_interpret()`` picks it on the CPU backend only; on the chip the
+kernel is always compiled.
 
 Layout construction/incremental re-packetization lives in ``FusedLayout`` /
 ``build_fused_layout`` below; the serving integration is
@@ -52,22 +52,20 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.coo import COOGraph, quantize_values
 from repro.core.fixed_point import QFormat
-from repro.core.ppr import _fixed_consts
-from repro.kernels.coo_spmv import _fixed_mul_u32
+from repro.core.ppr import _fixed_consts, _fixed_dangling_mass
+from repro.kernels.coo_spmv import (
+    ROW_BLOCK,
+    _fixed_mul_u32,
+    default_interpret,
+    dst_chunks,
+    pad_rows,
+    spmv_accumulate,
+)
 
 __all__ = [
     "FusedLayout", "build_fused_layout", "quantize_layout_rows",
     "assemble_value_rows", "fused_ppr_iteration", "default_interpret",
 ]
-
-
-@functools.lru_cache(maxsize=1)
-def default_interpret() -> bool:
-    """interpret=True unless a real TPU backend is present."""
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:  # pragma: no cover - no backend at all
-        return True
 
 
 # ---------------------------------------------------------------------------
@@ -80,9 +78,9 @@ class FusedLayout:
     Per dst block ``d`` the edges are grouped by source block and padded to
     whole packets (``row_*[d]``: [p_d, packet] with local indices; pad entries
     are zero-valued self-edges to local vertex 0 — they contribute nothing).
-    The assembled arrays carry one extra all-zero sentinel row at index
-    ``num_rows - 1``, addressed by prologue steps and by the sentinel step of
-    every empty dst block.
+    The assembled arrays end in all-zero rows up to a whole ``ROW_BLOCK``
+    tile; the first of them is the sentinel row that the step of every empty
+    dst block addresses.
 
     The rebuild is per-dst-block and deterministic, so an incremental rebuild
     of only the dirty blocks is array-equal to a fresh build of the merged
@@ -96,7 +94,7 @@ class FusedLayout:
     row_x: List[np.ndarray]      # per dst block: [p_d, packet] int32 local dst
     row_y: List[np.ndarray]      # per dst block: [p_d, packet] int32 local src
     row_val: List[np.ndarray]    # per dst block: [p_d, packet] f64 edge values
-    x2: np.ndarray               # [num_rows, packet] int32 (+ sentinel row)
+    x2: np.ndarray               # [num_rows, packet] int32 (+ zero rows)
     y2: np.ndarray               # [num_rows, packet] int32
     val2: np.ndarray             # [num_rows, packet] f32
     step_row: np.ndarray         # [num_steps] int32  step → edge row
@@ -105,9 +103,10 @@ class FusedLayout:
     step_first: np.ndarray       # [num_steps] int32  1 = zero the dst block
     step_last: np.ndarray        # [num_steps] int32  1 = combine + residual
 
-    @property
-    def n_prologue(self) -> int:
-        return self.n_blk
+    @functools.cached_property
+    def chunks(self):
+        """The launches of one iteration (``coo_spmv.dst_chunks``)."""
+        return dst_chunks(self.step_dst, self.n_blk)
 
     @property
     def num_steps(self) -> int:
@@ -151,10 +150,11 @@ def _build_dst_row(x, y, val, v_tile: int, packet: int, n_blk: int):
 
 
 def _assemble_rows(rows: Sequence[np.ndarray], packet: int, dtype) -> np.ndarray:
-    """Stack per-block rows and append the shared all-zero sentinel row."""
+    """Stack per-block rows, append the all-zero sentinel row and pad to
+    whole ``ROW_BLOCK`` tiles."""
     parts = [np.asarray(r, dtype) for r in rows if r.shape[0]]
     parts.append(np.zeros((1, packet), dtype))
-    return np.concatenate(parts, axis=0)
+    return pad_rows(np.concatenate(parts, axis=0))
 
 
 def assemble_value_rows(rows: Sequence[np.ndarray], packet: int,
@@ -200,14 +200,9 @@ def build_fused_layout(g: COOGraph, v_tile: int, packet: int,
     x2 = _assemble_rows(rows_x, packet, np.int32)
     y2 = _assemble_rows(rows_y, packet, np.int32)
     val2 = _assemble_rows(rows_v, packet, np.float32)
-    sentinel = x2.shape[0] - 1
-    # schedule: prologue folds dangling block b into dm; then the dst-major
-    # stream, with one sentinel step per empty dst block
-    srow = [sentinel] * n_blk
-    sdst = [0] * n_blk
-    ssrc = list(range(n_blk))
-    sfirst = [0] * n_blk
-    slast = [0] * n_blk
+    sentinel = sum(r.shape[0] for r in rows_x)
+    # schedule: the dst-major stream, with one sentinel step per empty dst block
+    srow, sdst, ssrc, sfirst, slast = [], [], [], [], []
     base = 0
     for d in range(n_blk):
         p_d = rows_x[d].shape[0]
@@ -259,48 +254,13 @@ def quantize_layout_rows(layout: FusedLayout, fmt: QFormat,
 
 
 # ---------------------------------------------------------------------------
-# the fused kernels
+# the fused kernel
 # ---------------------------------------------------------------------------
-def _spmv_accumulate_float(x_ref, y_ref, val_ref, ps_ref, out_ref):
-    x = x_ref[0, :].astype(jnp.int32)
-    y = y_ref[0, :].astype(jnp.int32)
-    val = val_ref[0, :]
-    contrib = val[:, None] * ps_ref[y, :]         # [P, K]
-    v_tile = out_ref.shape[0]
-    onehot = (x[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (x.shape[0], v_tile), 1))
-    out_ref[...] += jnp.dot(onehot.astype(contrib.dtype).T, contrib,
-                            preferred_element_type=out_ref.dtype)
-
-
-def _spmv_accumulate_fixed(frac_bits, x_ref, y_ref, val_ref, ps_ref, out_ref):
-    x = x_ref[0, :].astype(jnp.int32)
-    y = y_ref[0, :].astype(jnp.int32)
-    val = val_ref[0, :]
-    contrib = _fixed_mul_u32(val[:, None], ps_ref[y, :], frac_bits)
-    v_tile = out_ref.shape[0]
-    onehot = (x[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (x.shape[0], v_tile), 1))
-    acc = jnp.dot(onehot.astype(jnp.int32).T, contrib.astype(jnp.int32),
-                  preferred_element_type=jnp.int32)
-    out_ref[...] += acc.astype(jnp.uint32)
-
-
 def _valid_rows(dst_blk, v_tile: int, num_vertices: int):
     """[v_tile, 1] mask of real (non-pad) rows of this dst block."""
     rows = dst_blk * v_tile + jax.lax.broadcasted_iota(
         jnp.int32, (v_tile, 1), 0)
     return rows < num_vertices
-
-
-def _fold_residual(res_ref, pn, prev_f32_diff):
-    """Accumulate this dst block's |ΔP| into the [3, K] (L1, ∞, Σd²) output."""
-    r = res_ref[...]
-    res_ref[...] = jnp.stack([
-        r[0] + prev_f32_diff.sum(0),
-        jnp.maximum(r[1], prev_f32_diff.max(0)),
-        r[2] + (prev_f32_diff * prev_f32_diff).sum(0),
-    ])
 
 
 def _sat_add_u32(a, b, max_raw):
@@ -310,95 +270,72 @@ def _sat_add_u32(a, b, max_raw):
     return jnp.where(over, max_raw, s)
 
 
-def _kernel_float_fused(alpha, num_vertices, n_prologue,
-                        sr, sd, ss, sf, sl,
-                        x_ref, y_ref, val_ref, ps_ref, pd_ref, vmat_ref,
-                        dang_ref, out_ref, dm_ref, res_ref):
-    """One grid step: prologue dangling fold, or one SpMV packet; the last
-    packet of a dst block applies the eq. (1) combine + residual in place."""
+def _combine_float(alpha, num_vertices, xp, dm, vmat):
+    """eq. (1) in f32."""
+    return alpha * xp + (alpha / num_vertices) * dm + (1.0 - alpha) * vmat
+
+
+def _absdiff_float(pn, prev):
+    return jnp.abs(pn - prev)
+
+
+def _combine_fixed(frac_bits, alpha_raw, one_minus_alpha_raw, alpha_over_v_raw,
+                   max_raw, xp, dm, vmat):
+    """The exact ``_fixed_combine`` nesting (truncating limb multiplies,
+    saturating adds) — bit-identical to the composed ``make_ppr_fixed_step``."""
+    return _sat_add_u32(
+        _sat_add_u32(_fixed_mul_u32(alpha_raw, xp, frac_bits),
+                     _fixed_mul_u32(alpha_over_v_raw, dm, frac_bits),
+                     max_raw),
+        _fixed_mul_u32(one_minus_alpha_raw, vmat, frac_bits),
+        max_raw)
+
+
+def _absdiff_fixed(pn, prev):
+    """|pn − prev| of raw uint32 values as f32 (in-format values are < 2^31,
+    so the int32 difference cannot overflow)."""
+    i32 = functools.partial(jax.lax.bitcast_convert_type, new_dtype=jnp.int32)
+    return jnp.abs(i32(pn) - i32(prev)).astype(jnp.float32)
+
+
+def _fused_kernel(combine, absdiff, frac_bits, num_vertices,
+                  sr, sd, ss, sf, sl,
+                  x_ref, y_ref, val_ref, ps_ref, pd_ref, vmat_ref, dm_ref,
+                  out_ref, res_ref):
+    """One grid step = one edge packet; the last packet of a dst block applies
+    the eq. (1) combine in place and folds |ΔP| into the residual."""
     s = pl.program_id(0)
 
     @pl.when(s == 0)
     def _init():
-        dm_ref[...] = jnp.zeros_like(dm_ref)
         res_ref[...] = jnp.zeros_like(res_ref)
 
-    @pl.when(s < n_prologue)
-    def _fold_dangling():
-        dm_ref[...] += (dang_ref[...] * ps_ref[...]).sum(0, keepdims=True)
-
-    @pl.when((s >= n_prologue) & (sf[s] == 1))
+    @pl.when(sf[s] == 1)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    @pl.when(s >= n_prologue)
-    def _spmv():
-        _spmv_accumulate_float(x_ref, y_ref, val_ref, ps_ref, out_ref)
+    spmv_accumulate(sr[s] % ROW_BLOCK, x_ref, y_ref, val_ref, ps_ref, out_ref,
+                    frac_bits)
 
-    @pl.when((s >= n_prologue) & (sl[s] == 1))
+    @pl.when(sl[s] == 1)
     def _combine():
         v_tile = out_ref.shape[0]
-        pn = (alpha * out_ref[...]
-              + (alpha / num_vertices) * dm_ref[...]
-              + (1.0 - alpha) * vmat_ref[...])
+        pn = combine(out_ref[...], dm_ref[...], vmat_ref[...])
         pn = jnp.where(_valid_rows(sd[s], v_tile, num_vertices),
                        pn, jnp.zeros_like(pn))
         out_ref[...] = pn
-        _fold_residual(res_ref, pn, jnp.abs(pn - pd_ref[...]))
-
-
-def _kernel_fixed_fused(frac_bits, alpha_raw, one_minus_alpha_raw,
-                        alpha_over_v_raw, max_raw, num_vertices, n_prologue,
-                        sr, sd, ss, sf, sl,
-                        x_ref, y_ref, val_ref, ps_ref, pd_ref, vmat_ref,
-                        dang_ref, out_ref, dm_ref, res_ref):
-    """Fixed-point variant: raw uint32 SpMV + the exact ``_fixed_combine``
-    nesting (truncating limb multiplies, saturating adds) — bit-identical to
-    the composed ``make_ppr_fixed_step``."""
-    s = pl.program_id(0)
-
-    @pl.when(s == 0)
-    def _init():
-        dm_ref[...] = jnp.zeros_like(dm_ref)
-        res_ref[...] = jnp.zeros_like(res_ref)
-
-    @pl.when(s < n_prologue)
-    def _fold_dangling():
-        d = dang_ref[...].astype(jnp.uint32)
-        dm_ref[...] += (d * ps_ref[...]).astype(jnp.int32).sum(0, keepdims=True)
-
-    @pl.when((s >= n_prologue) & (sf[s] == 1))
-    def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(s >= n_prologue)
-    def _spmv():
-        _spmv_accumulate_fixed(frac_bits, x_ref, y_ref, val_ref, ps_ref, out_ref)
-
-    @pl.when((s >= n_prologue) & (sl[s] == 1))
-    def _combine():
-        v_tile = out_ref.shape[0]
-        dm = dm_ref[...].astype(jnp.uint32)
-        pn = _sat_add_u32(
-            _sat_add_u32(_fixed_mul_u32(alpha_raw, out_ref[...], frac_bits),
-                         _fixed_mul_u32(alpha_over_v_raw, dm, frac_bits),
-                         max_raw),
-            _fixed_mul_u32(one_minus_alpha_raw, vmat_ref[...], frac_bits),
-            max_raw)
-        pn = jnp.where(_valid_rows(sd[s], v_tile, num_vertices),
-                       pn, jnp.zeros_like(pn))
-        out_ref[...] = pn
-        prev = pd_ref[...]
-        diff = (jnp.maximum(pn, prev) - jnp.minimum(pn, prev)).astype(jnp.float32)
-        _fold_residual(res_ref, pn, diff)
+        d = absdiff(pn, pd_ref[...])          # pad rows: 0 - 0 = 0
+        res_ref[0:1, :] += d.sum(0, keepdims=True)
+        res_ref[1:2, :] = jnp.maximum(res_ref[1:2, :], d.max(0, keepdims=True))
+        res_ref[2:3, :] += (d * d).sum(0, keepdims=True)
 
 
 # ---------------------------------------------------------------------------
-# the launch
+# the launches
 # ---------------------------------------------------------------------------
 @functools.partial(
     jax.jit,
-    static_argnames=("v_tile", "packet", "n_blk", "num_steps", "num_vertices",
+    static_argnames=("v_tile", "packet", "n_blk", "chunks", "num_vertices",
                      "alpha", "fmt", "interpret"),
 )
 def fused_ppr_iteration(
@@ -410,20 +347,21 @@ def fused_ppr_iteration(
     x2: jax.Array,           # [num_rows, packet] int32 local dst
     y2: jax.Array,           # [num_rows, packet] int32 local src
     val2: jax.Array,         # [num_rows, packet] f32 (or uint32 raw if fixed)
-    dang: jax.Array,         # [n_blk * v_tile, 1] f32 dangling indicator (padded)
+    dangling: jax.Array,     # [V] dangling indicator
     vmat: jax.Array,         # [V, K] personalization matrix
     p: jax.Array,            # [V, K] current state
     *,
     v_tile: int,
     packet: int,
     n_blk: int,
-    num_steps: int,
+    chunks: tuple,
     num_vertices: int,
     alpha: float,
     fmt: Optional[QFormat] = None,
-    interpret: bool = True,
+    interpret: bool = False,
 ):
-    """One full eq. (1) iteration as a single Pallas launch.
+    """One full eq. (1) iteration: a dangling-mass reduction, then one Pallas
+    launch per schedule chunk (``FusedLayout.chunks``).
 
     Returns ``(P_next [V, K], res [3, K] f32)`` where ``res`` carries the
     per-column (L1, ∞, Σd²) of |P_next − P| — raw units for fixed point.  A
@@ -432,55 +370,68 @@ def fused_ppr_iteration(
     exit driver keys on.
     """
     k = p.shape[-1]
-    padded = n_blk * v_tile
-    grow = padded - num_vertices
+    grow = n_blk * v_tile - num_vertices
     p_pad = jnp.pad(p, ((0, grow), (0, 0)))
     vmat_pad = jnp.pad(vmat, ((0, grow), (0, 0)))
     if fmt is None:
-        kernel = functools.partial(_kernel_float_fused, alpha, num_vertices,
-                                   n_blk)
-        dm_dtype = jnp.float32
+        dm = (dangling.astype(jnp.float32) @ p)[None, :]
+        combine = functools.partial(_combine_float, alpha, num_vertices)
+        absdiff, frac_bits = _absdiff_float, None
     else:
+        dm = _fixed_dangling_mass(dangling.astype(jnp.uint32), p)[None, :]
         a_raw, oma_raw, aov_raw = _fixed_consts(fmt, num_vertices, alpha)
-        kernel = functools.partial(
-            _kernel_fixed_fused, fmt.frac_bits, a_raw, oma_raw, aov_raw,
-            np.uint32(fmt.max_raw), num_vertices, n_blk)
-        dm_dtype = jnp.int32
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(num_steps,),
-        in_specs=[
-            pl.BlockSpec((1, packet),
-                         lambda i, sr, sd, ss, sf, sl: (sr[i], 0)),   # x
-            pl.BlockSpec((1, packet),
-                         lambda i, sr, sd, ss, sf, sl: (sr[i], 0)),   # y
-            pl.BlockSpec((1, packet),
-                         lambda i, sr, sd, ss, sf, sl: (sr[i], 0)),   # val
-            pl.BlockSpec((v_tile, k),
-                         lambda i, sr, sd, ss, sf, sl: (ss[i], 0)),   # P src
-            pl.BlockSpec((v_tile, k),
-                         lambda i, sr, sd, ss, sf, sl: (sd[i], 0)),   # P dst
-            pl.BlockSpec((v_tile, k),
-                         lambda i, sr, sd, ss, sf, sl: (sd[i], 0)),   # V̄ dst
-            pl.BlockSpec((v_tile, 1),
-                         lambda i, sr, sd, ss, sf, sl: (ss[i], 0)),   # dangling
-        ],
-        out_specs=[
-            pl.BlockSpec((v_tile, k),
-                         lambda i, sr, sd, ss, sf, sl: (sd[i], 0)),   # P_next
-            pl.BlockSpec((1, k), lambda i, sr, sd, ss, sf, sl: (0, 0)),  # dm
-            pl.BlockSpec((3, k), lambda i, sr, sd, ss, sf, sl: (0, 0)),  # res
-        ],
-    )
-    out, _, res = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((padded, k), p.dtype),
-            jax.ShapeDtypeStruct((1, k), dm_dtype),
-            jax.ShapeDtypeStruct((3, k), jnp.float32),
-        ],
-        interpret=interpret,
-    )(step_row, step_dst, step_src, step_first, step_last,
-      x2, y2, val2, p_pad, p_pad, vmat_pad, dang)
-    return out[:num_vertices], res
+        combine = functools.partial(_combine_fixed, fmt.frac_bits, a_raw,
+                                    oma_raw, aov_raw, np.uint32(fmt.max_raw))
+        absdiff, frac_bits = _absdiff_fixed, fmt.frac_bits
+    kernel = functools.partial(_fused_kernel, combine, absdiff, frac_bits,
+                               num_vertices)
+
+    def tile(i, sr, sd, ss, sf, sl):
+        return sr[i] // ROW_BLOCK, 0
+
+    def src(i, sr, sd, ss, sf, sl):
+        return ss[i], 0
+
+    def dst(i, sr, sd, ss, sf, sl):
+        return sd[i], 0
+
+    def whole(i, sr, sd, ss, sf, sl):
+        return 0, 0
+
+    outs, resids = [], []
+    for s0, s1, d0, d1 in chunks:
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(s1 - s0,),
+            in_specs=[
+                pl.BlockSpec((ROW_BLOCK, packet), tile),     # x
+                pl.BlockSpec((ROW_BLOCK, packet), tile),     # y
+                pl.BlockSpec((ROW_BLOCK, packet), tile),     # val
+                pl.BlockSpec((v_tile, k), src),              # P src
+                pl.BlockSpec((v_tile, k), dst),              # P dst
+                pl.BlockSpec((v_tile, k), dst),              # V̄ dst
+                pl.BlockSpec((1, k), whole),                 # dangling mass
+            ],
+            out_specs=[
+                pl.BlockSpec((v_tile, k),
+                             lambda i, sr, sd, ss, sf, sl, d0=d0:
+                             (sd[i] - d0, 0)),               # P_next
+                pl.BlockSpec((3, k), whole),                 # residual
+            ],
+        )
+        out, res = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct(((d1 - d0) * v_tile, k), p.dtype),
+                jax.ShapeDtypeStruct((3, k), jnp.float32),
+            ],
+            interpret=interpret,
+        )(step_row[s0:s1], step_dst[s0:s1], step_src[s0:s1],
+          step_first[s0:s1], step_last[s0:s1],
+          x2, y2, val2, p_pad, p_pad, vmat_pad, dm)
+        outs.append(out)
+        resids.append(res)
+    res = jnp.stack(resids)
+    res = jnp.stack([res[:, 0].sum(0), res[:, 1].max(0), res[:, 2].sum(0)])
+    return jnp.concatenate(outs, axis=0)[:num_vertices], res

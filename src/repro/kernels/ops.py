@@ -15,7 +15,12 @@ import numpy as np
 
 from repro.core.coo import BlockedCOO
 from repro.core.fixed_point import QFormat
-from repro.kernels.coo_spmv import coo_spmv_pallas
+from repro.kernels.coo_spmv import (
+    coo_spmv_pallas,
+    default_interpret,
+    dst_chunks,
+    pad_rows,
+)
 from repro.kernels.fixed_matmul import quantized_matmul_pallas
 
 
@@ -43,10 +48,11 @@ def coo_spmv(
     p: jax.Array,
     *,
     fmt: Optional[QFormat] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Streaming SpMM via the Pallas kernel.  p: [V_padded, K] where V_padded =
-    n_src * v_tile (caller pads).  fmt=None → float; else p/val are raw uint32."""
+    n_src * v_tile (caller pads).  fmt=None → float; else p/val are raw uint32.
+    ``interpret=None`` interprets on the CPU backend and compiles elsewhere."""
     meta = getattr(blocked, "_packet_meta", None)
     if meta is None:
         meta = packet_metadata(blocked)
@@ -56,23 +62,23 @@ def coo_spmv(
     num_packets = packet_dst.shape[0]
     pk = blocked.packet
     xp_, yp_ = blocked.packed_indices()   # uint16 when v_tile ≤ 65536 (½ stream)
-    x2 = jnp.asarray(xp_.reshape(num_packets, pk))
-    y2 = jnp.asarray(yp_.reshape(num_packets, pk))
+    x2 = jnp.asarray(pad_rows(xp_.reshape(num_packets, pk)))
+    y2 = jnp.asarray(pad_rows(yp_.reshape(num_packets, pk)))
     if fmt is None:
-        val2 = jnp.asarray(blocked.val.reshape(num_packets, pk))
+        val2 = blocked.val
         frac_bits = None
     else:
-        raw = np.minimum(
+        val2 = np.minimum(
             np.floor(np.clip(blocked.val.astype(np.float64), 0, None) * fmt.scale),
             fmt.max_raw,
         ).astype(np.uint32)
-        val2 = jnp.asarray(raw.reshape(num_packets, pk))
         frac_bits = fmt.frac_bits
     out = coo_spmv_pallas(
-        x2, y2, val2, p,
+        x2, y2, jnp.asarray(pad_rows(val2.reshape(num_packets, pk))), p,
         jnp.asarray(packet_dst), jnp.asarray(packet_src), jnp.asarray(first),
-        v_tile=blocked.v_tile, packet=pk, n_dst=blocked.n_dst,
-        num_packets=num_packets, frac_bits=frac_bits, interpret=interpret,
+        v_tile=blocked.v_tile, packet=pk,
+        chunks=dst_chunks(packet_dst, blocked.n_dst), frac_bits=frac_bits,
+        interpret=default_interpret() if interpret is None else interpret,
     )
     # dst blocks with zero packets hold uninitialized memory — mask them.
     mask = jnp.asarray(np.repeat(touched, blocked.v_tile))

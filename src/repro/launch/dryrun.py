@@ -1,12 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks device count on first init).
-
 """Multi-pod dry-run: lower + compile every (architecture × input shape) on the
-production meshes, and extract memory / cost / collective analyses.
+production meshes, and extract memory / cost / collective analyses.  Runs on
+the CPU, on 512 host devices:
 
-    PYTHONPATH=src python -m repro.launch.dryrun [--arch A] [--shape S]
-        [--mesh single|multi|both] [--out experiments/dryrun]
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.dryrun [--arch A]
+        [--shape S] [--mesh single|multi|both] [--out experiments/dryrun]
 
 Per cell it writes ``<out>/<mesh>/<arch>__<shape>.json`` with:
   - memory_analysis (per-device bytes: args / outputs / temps / peak)
@@ -20,6 +17,7 @@ the framework — the run fails loudly.
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 
@@ -40,7 +38,7 @@ from repro.distributed.sharding import (
     set_sharding_context,
 )
 from repro.launch import specs as S
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import cpu_host_devices, make_production_mesh
 from repro.models.transformer import build_model
 from repro.roofline.analysis import (
     PEAK_FLOPS,
@@ -158,6 +156,7 @@ def main():
     ap.add_argument("--out", default="experiments/dryrun")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    cpu_host_devices(512)
 
     meshes = []
     if args.mesh in ("single", "both"):

@@ -1,11 +1,8 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks device count on first init).
-
 """Dry-run of the paper's own workload on the production meshes: the
 dst-partitioned streaming SpMV PPR iteration, lowered + compiled at pod scale.
+Runs on the CPU, on 512 host devices:
 
-    PYTHONPATH=src python -m repro.launch.ppr_dryrun [--workload ppr-pod-16m]
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.ppr_dryrun [--workload ppr-pod-16m]
 
 The model axis partitions the vertex space (the paper's URAM → per-chip
 memory); the data axis batches independent κ-groups of personalization
@@ -13,15 +10,15 @@ vertices (the paper's request batching, scaled 16×).
 """
 import argparse
 import json
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.ppr_paper import PPR_WORKLOADS
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import cpu_host_devices, make_production_mesh
 from repro.roofline.analysis import HBM_BW, ICI_BW, collective_bytes
 
 SDS = jax.ShapeDtypeStruct
@@ -52,7 +49,7 @@ def build_ppr_step(w, mesh):
         # columns over data so the model-axis all-gather never spans them
         # (16× less collective traffic than gathering all K_total columns).
         kspec = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-        return shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P("model"), P("model"), P("model"),
                       P("model", kspec), P(), P("model", kspec)),
@@ -85,6 +82,7 @@ def main():
                     choices=sorted(PPR_WORKLOADS))
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args()
+    cpu_host_devices(512)
     w = PPR_WORKLOADS[args.workload]
     for mesh_name, mesh in [
         ("single_pod_16x16", make_production_mesh(multi_pod=False)),

@@ -10,16 +10,15 @@ rankings against the float64 CPU oracle at convergence (§5.3 metrics).
 ``--serve`` routes the same workload through ``PPRService`` (κ-batched waves,
 top-K, telemetry) instead of the raw ``batched_ppr`` loop; ``--shards N``
 additionally registers the graph on an N-way ``jax.sharding`` mesh so waves
-run the sharded step bodies — the multi-host serving path.  When fewer than N
-devices are visible, N host devices are forced (CPU demo of the layout; on a
-real platform the flag is a no-op because devices are already there):
+run the sharded step bodies — the multi-device serving path.  On the chip it
+needs N devices; with ``JAX_PLATFORMS=cpu`` it runs on N host devices (a CPU
+demo of the layout):
 
-    PYTHONPATH=src python -m repro.launch.ppr_run --serve --shards 4
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.ppr_run --serve --shards 4
 """
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 
@@ -83,15 +82,12 @@ def _parse_args(argv=None):
 
 def main():
     args = _parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    from repro.launch.mesh import cpu_host_devices
+
     if args.shards > 1:
-        # must be set before the jax backend initializes; harmless when enough
-        # real devices exist or the backend already came up (_serve then
-        # reports the actual device shortfall with a remedy)
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "--xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count={args.shards}"
-            ).strip()
+        cpu_host_devices(args.shards)
+    use_compile_cache()
 
     import numpy as np
 
@@ -144,8 +140,8 @@ def _serve(args, g, vertices, fmt, label):
     parity with the direct path is covered by tests/test_sharded_serving.py.
     This driver reports serving throughput and per-mesh wave telemetry."""
     import jax
-    import numpy as np
 
+    from repro.launch.mesh import make_mesh
     from repro.ppr_serving import PPRQuery, PPRService
 
     mesh = None
@@ -153,11 +149,8 @@ def _serve(args, g, vertices, fmt, label):
         if jax.device_count() < args.shards:
             raise SystemExit(
                 f"--shards {args.shards} needs {args.shards} devices, have "
-                f"{jax.device_count()} (the jax backend initialized before "
-                f"this driver could force host devices — set XLA_FLAGS="
-                f"--xla_force_host_platform_device_count={args.shards} "
-                f"up front)")
-        mesh = jax.make_mesh((args.shards,), ("shard",))
+                f"{jax.device_count()} on {jax.default_backend()}")
+        mesh = make_mesh((args.shards,), ("shard",))
     svc = PPRService(kappa=args.kappa, iterations=args.iterations,
                      alpha=args.alpha, cache_capacity=0,      # measure compute
                      tracing=_tracing(args))

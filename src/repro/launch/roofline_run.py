@@ -1,22 +1,20 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede every other import (jax locks device count on first init).
-
 """Structured (trip-count-correct) roofline for every cell on the single-pod
-mesh (§Roofline is single-pod per the run-book).
+mesh (§Roofline is single-pod per the run-book).  Runs on the CPU, on 512 host
+devices:
 
-    PYTHONPATH=src python -m repro.launch.roofline_run [--arch A] [--shape S]
-        [--out experiments/roofline] [--variant baseline]
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.roofline_run
+        [--arch A] [--shape S] [--out experiments/roofline] [--variant baseline]
 """
 import argparse
 import json
+import os
 import time
 import traceback
 
 import jax.numpy as jnp
 
 from repro.configs import LONG_CONTEXT_ARCHS, SHAPES, get_config, list_archs
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import cpu_host_devices, make_production_mesh
 from repro.roofline.structured import structured_roofline
 
 MICROBATCHES = {"train_4k": 8}
@@ -68,6 +66,7 @@ def main():
     ap.add_argument("--variant", default="baseline")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    cpu_host_devices(512)
 
     mesh = make_production_mesh(multi_pod=False)
     archs = [args.arch] if args.arch else list_archs()

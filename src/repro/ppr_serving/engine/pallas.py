@@ -22,8 +22,9 @@ minimum nonzero raw diff, 1.0, is exactly representable in f32), period-2
 cycles are still caught by comparing against S_{t-2}, and the parity of the
 remaining budget picks the bit-identical return state.
 
-Off-TPU the kernels run under ``interpret=True`` (slow, bit-exact), so the
-family stays correct — and testable in CI — on CPU-only hosts.
+On the CPU backend the kernels run under ``interpret=True`` (slow,
+bit-exact), so the family stays testable in CI; on the chip they are always
+compiled.
 """
 from __future__ import annotations
 
@@ -46,7 +47,8 @@ from repro.kernels.fused_ppr import (
 from repro.ppr_serving.engine.base import WaveEngine, WavePlan, register_engine
 from repro.ppr_serving.graphs import RegisteredGraph
 
-__all__ = ["PallasRegisteredGraph", "PallasFloatEngine", "PallasFixedEngine"]
+__all__ = ["PallasRegisteredGraph", "PallasFloatEngine", "PallasFixedEngine",
+           "fused_step_operands"]
 
 DEFAULT_V_TILE = 512
 
@@ -86,9 +88,6 @@ class PallasRegisteredGraph(RegisteredGraph):
         """Device uploads of the schedule + localized edge topology."""
         if self._fused_dev is None:
             lay = self.fused_layout()
-            dang = np.zeros((lay.n_blk * lay.v_tile, 1), np.float32)
-            dang[:self.num_vertices, 0] = np.asarray(self.graph.dangling,
-                                                     np.float32)
             self._fused_dev = {
                 "step_row": jnp.asarray(lay.step_row),
                 "step_dst": jnp.asarray(lay.step_dst),
@@ -97,7 +96,6 @@ class PallasRegisteredGraph(RegisteredGraph):
                 "step_last": jnp.asarray(lay.step_last),
                 "x2": jnp.asarray(lay.x2),
                 "y2": jnp.asarray(lay.y2),
-                "dang": jnp.asarray(dang),
             }
         return self._fused_dev
 
@@ -164,22 +162,29 @@ class PallasRegisteredGraph(RegisteredGraph):
 # ---------------------------------------------------------------------------
 # wave plumbing
 # ---------------------------------------------------------------------------
-def _bind_fused_step(rg: PallasRegisteredGraph, fmt: Optional[QFormat],
-                     alpha: float, cell: dict):
-    """Step closure over the graph's current fused device state.  Each launch
-    parks the kernel's [3, K] residual in ``cell`` for the iterate driver."""
+def fused_step_operands(rg: PallasRegisteredGraph, fmt: Optional[QFormat],
+                        alpha: float):
+    """``(graph operands, statics)`` of ``fused_ppr_iteration`` on ``rg``'s
+    current fused device state; a call appends ``(Vmat, P)``."""
     lay = rg.fused_layout()
     dev = rg.fused_topology()
-    val2 = rg.fused_values(fmt)
+    operands = (dev["step_row"], dev["step_dst"], dev["step_src"],
+                dev["step_first"], dev["step_last"], dev["x2"], dev["y2"],
+                rg.fused_values(fmt), rg.dangling)
     statics = dict(v_tile=lay.v_tile, packet=lay.packet, n_blk=lay.n_blk,
-                   num_steps=lay.num_steps, num_vertices=lay.num_vertices,
+                   chunks=lay.chunks, num_vertices=lay.num_vertices,
                    alpha=alpha, fmt=fmt, interpret=default_interpret())
+    return operands, statics
+
+
+def _bind_fused_step(rg: PallasRegisteredGraph, fmt: Optional[QFormat],
+                     alpha: float, cell: dict):
+    """Step closure over the graph's current fused device state.  Each call
+    parks the iteration's [3, K] residual in ``cell`` for the iterate driver."""
+    operands, statics = fused_step_operands(rg, fmt, alpha)
 
     def step(Vmat, P):
-        P_next, res = fused_ppr_iteration(
-            dev["step_row"], dev["step_dst"], dev["step_src"],
-            dev["step_first"], dev["step_last"],
-            dev["x2"], dev["y2"], val2, dev["dang"], Vmat, P, **statics)
+        P_next, res = fused_ppr_iteration(*operands, Vmat, P, **statics)
         cell["res"] = res
         return P_next
 

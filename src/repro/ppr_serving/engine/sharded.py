@@ -9,6 +9,9 @@ single-device ``FixedEngine`` would.  Per-shard raw accumulation is exact and
 each destination row lives on exactly one shard, so ``ShardedFixedEngine`` is
 *bit-identical* to ``FixedEngine``; the float pair is numerically equal.
 
+Bucket arrays are placed one shard per device (``NamedSharding`` on the
+mesh axis), at registration and at every re-partition.
+
 Delta ingestion re-buckets only the destination ranges a merge touched
 (``refresh_partition_after_delta``), falling back to a full re-partition when
 the delta moves the ceil-division layout itself (vertex growth changing
@@ -18,8 +21,9 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax.numpy as jnp
+import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.fixed_point import QFormat
 from repro.core.ppr import (
@@ -37,6 +41,11 @@ __all__ = ["ShardedFloatEngine", "ShardedFixedEngine"]
 # ---------------------------------------------------------------------------
 # partition state helpers — operate on a ShardedRegisteredGraph's buckets
 # ---------------------------------------------------------------------------
+def _place(rg, flat: np.ndarray) -> jax.Array:
+    """Upload a flat [S · max_e] bucket array one shard per device."""
+    return jax.device_put(flat, NamedSharding(rg.mesh, PartitionSpec(rg.axis)))
+
+
 def partition_topology(rg) -> None:
     """(Re-)bucket the *unpadded* edge stream by destination range; pad edges
     would only inflate shard 0 with zero slots the per-shard packet padding
@@ -49,25 +58,25 @@ def partition_topology(rg) -> None:
     rg._host_x = sx.reshape(s, -1)
     rg._host_y = sy.reshape(s, -1)
     rg._host_val = sval.reshape(s, -1)
-    rg.sharded_x = jnp.asarray(sx)
-    rg.sharded_y = jnp.asarray(sy)
-    rg.sharded_val = jnp.asarray(sval)
+    rg.sharded_x = _place(rg, sx)
+    rg.sharded_y = _place(rg, sy)
+    rg.sharded_val = _place(rg, sval)
     for fmt in set(rg._sharded_quantized) | set(rg._sharded_quant_host):
         _, _, sq = partition_edges_by_dst(
             rg.source.x, rg.source.y, rg._quantize_host(fmt),
             rg.num_vertices, rg.n_shards, packet=rg.packet)
         rg._sharded_quant_host[fmt] = sq.reshape(s, -1)
-        rg._sharded_quantized[fmt] = jnp.asarray(sq)
+        rg._sharded_quantized[fmt] = _place(rg, sq)
 
 
-def partition_format(rg, fmt: QFormat) -> jnp.ndarray:
+def partition_format(rg, fmt: QFormat) -> jax.Array:
     """Raw uint32 edge shard values in the partitioned layout (cached)."""
     if fmt not in rg._sharded_quantized:
         _, _, sval = partition_edges_by_dst(
             rg.source.x, rg.source.y, rg._quantize_host(fmt),
             rg.num_vertices, rg.n_shards, packet=rg.packet)
         rg._sharded_quant_host[fmt] = sval.reshape(rg.n_shards, -1)
-        rg._sharded_quantized[fmt] = jnp.asarray(sval)
+        rg._sharded_quantized[fmt] = _place(rg, sval)
     return rg._sharded_quantized[fmt]
 
 
@@ -102,11 +111,11 @@ def refresh_partition_after_delta(rg, info) -> None:
         for fmt, hq in rg._sharded_quant_host.items():
             hq[s, :] = 0
             hq[s, :n] = rg._quantized_host[fmt][m]
-    rg.sharded_x = jnp.asarray(rg._host_x.reshape(-1))
-    rg.sharded_y = jnp.asarray(rg._host_y.reshape(-1))
-    rg.sharded_val = jnp.asarray(rg._host_val.reshape(-1))
+    rg.sharded_x = _place(rg, rg._host_x.reshape(-1))
+    rg.sharded_y = _place(rg, rg._host_y.reshape(-1))
+    rg.sharded_val = _place(rg, rg._host_val.reshape(-1))
     for fmt, hq in rg._sharded_quant_host.items():
-        rg._sharded_quantized[fmt] = jnp.asarray(hq.reshape(-1))
+        rg._sharded_quantized[fmt] = _place(rg, hq.reshape(-1))
 
 
 # ---------------------------------------------------------------------------

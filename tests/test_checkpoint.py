@@ -10,6 +10,7 @@ import pytest
 
 from repro.configs import get_config, smoke_config
 from repro.data import DataConfig, synthetic_batch
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.training import (
     AdamWConfig,
@@ -97,7 +98,7 @@ def test_restore_respects_shardings(tmp_path, setup):
     cfg, api, step, dcfg = setup
     params = api.init_params(jax.random.PRNGKey(0))
     save(str(tmp_path), 3, params)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), params)

@@ -24,17 +24,17 @@ def _run(script: str) -> str:
 def test_sharded_spmv_matches_dense():
     print(_run("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.compat import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.core.spmv import make_sharded_spmv, partition_edges_by_dst, spmv_float
         from repro.graphs import erdos_renyi
         g = erdos_renyi(512, 4096, seed=0)
-        mesh = jax.make_mesh((8,), ("model",))
+        mesh = make_mesh((8,), ("model",))
         k = 4
         rng = np.random.default_rng(0)
         p = (rng.random((512, k)) / 512).astype(np.float32)
         x, y, v = partition_edges_by_dst(g.x, g.y, g.val, 512, 8)
         f = make_sharded_spmv(mesh, "model", 512)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out = f(jnp.asarray(x), jnp.asarray(y), jnp.asarray(v), jnp.asarray(p))
         ref = spmv_float(jnp.asarray(g.x), jnp.asarray(g.y), jnp.asarray(g.val),
                          jnp.asarray(p), 512)
@@ -47,15 +47,15 @@ def test_sharded_spmv_matches_dense():
 def test_compressed_psum_error_feedback():
     print(_run("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
         from repro.distributed.collectives import compressed_psum
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         g = rng.standard_normal((8, 64)).astype(np.float32) * 0.1
         def step(gs, rs):
             return compressed_psum(gs, rs, "data", frac_bits=8)
-        f = jax.jit(shard_map(step, mesh=mesh,
+        f = jax.jit(jax.shard_map(step, mesh=mesh,
                     in_specs=(P("data"), P("data")), out_specs=(P("data"), P("data"))))
         r = jnp.zeros_like(jnp.asarray(g))
         red, r2 = f(jnp.asarray(g), r)
@@ -82,6 +82,7 @@ def test_small_mesh_train_and_decode_lowering():
     lower+compile with the production sharding rules."""
     print(_run("""
         import dataclasses, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config, smoke_config
         from repro.models import build_model
@@ -93,7 +94,7 @@ def test_small_mesh_train_and_decode_lowering():
         cfg = dataclasses.replace(smoke_config(get_config("gemma-2b")),
                                   d_model=128, num_heads=4, num_kv_heads=1, head_dim=32)
         api = build_model(cfg)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         params_s = S.params_specs(api)
         pshard = param_shardings(params_s, mesh, cfg=cfg)
         set_sharding_context(mesh)
@@ -109,8 +110,7 @@ def test_small_mesh_train_and_decode_lowering():
         bshard = batch_shardings(batch_s, mesh)
         c = jax.jit(step, in_shardings=(state_shard, bshard),
                     out_shardings=(state_shard, None)).lower(state_s, batch_s).compile()
-        from repro.compat import compiled_cost_analysis
-        print("train compile OK; flops:", compiled_cost_analysis(c).get("flops"))
+        print("train compile OK; flops:", c.cost_analysis().get("flops"))
         # decode
         shape_d = ShapeConfig("d", "decode", 64, 8)
         token_s, pos_s, cache_s = S.decode_specs(cfg, shape_d, api)
@@ -127,11 +127,12 @@ def test_small_mesh_train_and_decode_lowering():
 def test_param_shardings_cover_all_leaves():
     print(_run("""
         import jax
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.launch import specs as S
         from repro.distributed.sharding import param_shardings
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         for arch in ["mixtral-8x7b", "zamba2-1.2b", "whisper-medium"]:
             cfg = get_config(arch)
             api = build_model(cfg)
@@ -149,6 +150,7 @@ def test_elastic_rescale_checkpoint():
     improving and params match a bit-exact single-mesh reference restore."""
     print(_run("""
         import dataclasses, jax, jax.numpy as jnp, numpy as np, tempfile
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config, smoke_config
         from repro.models import build_model
@@ -167,7 +169,7 @@ def test_elastic_rescale_checkpoint():
         step = make_train_step(api.loss_fn,
                                AdamWConfig(lr=1e-3, warmup_steps=1, total_steps=20))
 
-        mesh_big = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_big = make_mesh((4, 2), ("data", "model"))
         set_sharding_context(mesh_big)
         params = api.init_params(jax.random.PRNGKey(0))
         psh = param_shardings(params, mesh_big, cfg=cfg)
@@ -183,7 +185,7 @@ def test_elastic_rescale_checkpoint():
         # rebuilds the train step — reusing the old `step` function object
         # would hit jax's trace cache, whose jaxpr bakes in mesh_big's
         # sharding constraints.
-        mesh_small = jax.make_mesh((2, 2), ("data", "model"))
+        mesh_small = make_mesh((2, 2), ("data", "model"))
         set_sharding_context(mesh_small)
         step = make_train_step(api.loss_fn,
                                AdamWConfig(lr=1e-3, warmup_steps=1, total_steps=20))

@@ -54,7 +54,7 @@ def test_truncation_towards_zero(v, fmt):
     """Quantization never rounds up (the paper's truncation policy).
     Checked in exact integer→f64 math (to_float's f32 cast may round)."""
     import jax
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         raw = int(np.asarray(fmt.from_float(np.float64(v))))
     q = raw / fmt.scale   # exact for ≤53-bit significands
     assert q <= v + 1e-12

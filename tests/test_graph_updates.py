@@ -532,13 +532,14 @@ def test_sharded_apply_delta_equivalence():
     fresh sharded registration AND to single-device serving."""
     print(_run("""
         import numpy as np, jax
+        from repro.launch.mesh import make_mesh
         from repro.graphs import holme_kim_powerlaw
         from repro.graph_updates import random_delta
         from repro.ppr_serving import PPRQuery, PPRService
 
         g = holme_kim_powerlaw(203, m=4, seed=2)        # 203 % 4 != 0
         rng = np.random.default_rng(1)
-        mesh = jax.make_mesh((4,), ("shard",))
+        mesh = make_mesh((4,), ("shard",))
 
         for grow, label in ((0, "incremental-bucket"), (5, "full-repartition")):
             d = random_delta(g, rng, n_add=15, n_remove=6, grow=grow)

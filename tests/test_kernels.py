@@ -51,6 +51,29 @@ def test_coo_spmv_fixed_bit_exact(fmt):
     assert (out == ref).all(), "fixed-point kernel must be bit-exact"
 
 
+@pytest.mark.parametrize("fmt", [None, Q1_25], ids=["f32", "q1.25"])
+def test_coo_spmv_multi_launch(monkeypatch, fmt):
+    """A schedule cut into several launches (empty dst blocks inside the
+    cuts) gives the single-launch result."""
+    import repro.kernels.coo_spmv as coo_spmv
+    v, k = 700, 4
+    g = _random_graph(v, 1500, seed=11)
+    rng = np.random.default_rng(2)
+    p = (rng.random((v, k)) / v).astype(np.float32)
+    if fmt is not None:
+        p = np.floor(p * fmt.scale).astype(np.uint32)
+    outs = []
+    for limit in (coo_spmv.MAX_LAUNCH_STEPS, 16):
+        monkeypatch.setattr(coo_spmv, "MAX_LAUNCH_STEPS", limit)
+        blocked = BlockedCOO.build(g, v_tile=64, packet=32)
+        pp = kops.pad_p_for_blocks(jnp.asarray(p), blocked)
+        outs.append(np.asarray(kops.coo_spmv(blocked, pp, fmt=fmt,
+                                             interpret=True))[:v])
+    assert coo_spmv.dst_chunks(kops.packet_metadata(blocked)[0],
+                               blocked.n_dst)[1:], "needs several launches"
+    np.testing.assert_array_equal(outs[0], outs[1])
+
+
 def test_blocked_coo_roundtrip():
     """Blocking preserves the edge multiset (local→global reconstruction)."""
     g = _random_graph(300, 2000, seed=7)

@@ -15,13 +15,17 @@ from repro.autotune.convergence import ConvergencePolicy, run_until_converged  #
 from repro.core.coo import COOGraph  # noqa: E402
 from repro.core.fixed_point import format_for_bits  # noqa: E402
 from repro.graph_updates.delta import EdgeDelta  # noqa: E402
-from repro.kernels.fused_ppr import build_fused_layout  # noqa: E402
+from repro.kernels.fused_ppr import (  # noqa: E402
+    build_fused_layout,
+    fused_ppr_iteration,
+)
 from repro.ppr_serving import (  # noqa: E402
     PallasRegisteredGraph,
     PPRQuery,
     PPRService,
     get_engine,
 )
+from repro.ppr_serving.engine.pallas import fused_step_operands  # noqa: E402
 
 ALPHA = 0.85
 FMT = format_for_bits(20)
@@ -205,3 +209,46 @@ def test_layout_covers_every_edge_once():
     assert lay.step_row.shape == lay.step_dst.shape
     assert int(lay.step_first.sum()) == lay.n_blk  # one zero per dst block
     assert int(lay.step_last.sum()) == lay.n_blk   # one combine per dst block
+
+
+def test_dst_chunks_cut_on_dst_blocks():
+    from repro.kernels.coo_spmv import dst_chunks
+    sd = np.array([0, 0, 0, 2, 2, 3, 5, 5, 5, 5])     # blocks 1 and 4 empty
+    chunks = dst_chunks(sd, 7, max_steps=5)
+    assert chunks == ((0, 5, 0, 3), (5, 10, 3, 7))
+    for s0, s1, d0, d1 in chunks:
+        assert s1 - s0 <= 5
+        assert d0 <= sd[s0:s1].min() and sd[s0:s1].max() < d1
+    assert dst_chunks(sd, 7) == ((0, 10, 0, 7),)
+    with pytest.raises(ValueError):
+        dst_chunks(sd, 7, max_steps=3)                 # block 5 needs 4 steps
+
+
+@pytest.mark.parametrize("fixed", [True, False], ids=["q", "f32"])
+def test_multi_launch_iteration_equals_one_launch(monkeypatch, fixed):
+    """An iteration cut into several launches gives the same state and the
+    same residual as one launch (raw-bit equal on the fixed path)."""
+    import repro.kernels.coo_spmv as coo_spmv
+    g = _graph(seed=6)
+    pers = [2, 50, 640, 9]
+    key, fmt = ("pallas_fixed", FMT) if fixed else ("pallas_float", None)
+
+    def run():
+        rg = _pallas_rg(g)
+        plan = get_engine(key).plan(rg, fmt, alpha=ALPHA, iterations=3)
+        P, _ = _drive(plan, pers, 3)
+        Vmat = plan.initial(jnp.asarray(pers, jnp.int32))
+        operands, statics = fused_step_operands(rg, fmt, ALPHA)
+        res = fused_ppr_iteration(*operands, Vmat, P, **statics)[1]
+        return P, res, len(rg.fused_layout().chunks)
+
+    P1, r1, n1 = run()
+    monkeypatch.setattr(coo_spmv, "MAX_LAUNCH_STEPS", 12)
+    P2, r2, n2 = run()
+    assert n1 == 1 and n2 > 2
+    if fixed:
+        assert bool(jnp.array_equal(P1, P2))
+        assert bool(jnp.array_equal(r1[1], r2[1]))     # ∞-residual: a max
+    else:
+        assert float(jnp.abs(P1 - P2).max()) == 0.0
+    np.testing.assert_allclose(np.asarray(r1), np.asarray(r2), rtol=1e-6)

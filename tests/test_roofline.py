@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.roofline.analysis import (
     collective_bytes,
     model_flops_forward,
@@ -32,7 +33,7 @@ def test_collective_parse_fixture():
 
 def test_collective_parse_real_module():
     """Parse a real SPMD-partitioned module containing a psum."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def f(x):

@@ -34,7 +34,7 @@ def test_sharded_spmv_parity_nondivisible_vertices():
     The fixed path must be bit-for-bit; the float path numerically equal."""
     print(_run("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.compat import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.core.fixed_point import Q1_25
         from repro.core.spmv import (make_sharded_spmv, make_sharded_spmv_fixed,
                                      partition_edges_by_dst, sharded_vertex_layout,
@@ -45,14 +45,14 @@ def test_sharded_spmv_parity_nondivisible_vertices():
         v_local, v_pad = sharded_vertex_layout(V, S)
         assert v_local == 63 and v_pad == 504
         g = erdos_renyi(V, 4096, seed=0)
-        mesh = jax.make_mesh((S,), ("shard",))
+        mesh = make_mesh((S,), ("shard",))
         rng = np.random.default_rng(0)
         p = (rng.random((V, 4)) / V).astype(np.float32)
 
         # float path
         x, y, v = partition_edges_by_dst(g.x, g.y, g.val, V, S)
         f = make_sharded_spmv(mesh, "shard", V)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out = f(jnp.asarray(x), jnp.asarray(y), jnp.asarray(v), jnp.asarray(p))
         ref = spmv_float(jnp.asarray(g.x), jnp.asarray(g.y), jnp.asarray(g.val),
                          jnp.asarray(p), V)
@@ -67,7 +67,7 @@ def test_sharded_spmv_parity_nondivisible_vertices():
         assert vq.dtype == np.uint32, vq.dtype     # partitioner preserves dtype
         praw = fmt.from_float(jnp.asarray(p))
         ff = make_sharded_spmv_fixed(mesh, "shard", V, fmt)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             outq = ff(jnp.asarray(xq), jnp.asarray(yq), jnp.asarray(vq), praw)
         refq = spmv_fixed(jnp.asarray(g.x), jnp.asarray(g.y), jnp.asarray(vraw),
                           praw, V, fmt)
@@ -83,6 +83,7 @@ def test_sharded_ppr_steps_match_single_device():
     over 8 shards."""
     print(_run("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.core.fixed_point import Q1_23
         from repro.core.ppr import (make_ppr_fixed_step, make_ppr_sharded_fixed_step,
                                     make_ppr_sharded_float_step,
@@ -93,7 +94,7 @@ def test_sharded_ppr_steps_match_single_device():
 
         V, S, alpha = 389, 8, 0.85
         g = holme_kim_powerlaw(V, m=4, seed=3)
-        mesh = jax.make_mesh((S,), ("shard",))
+        mesh = make_mesh((S,), ("shard",))
         pers = jnp.asarray([0, 17, 388], jnp.int32)
         dang = jnp.asarray(g.dangling)
 
@@ -133,12 +134,13 @@ def test_service_mesh_vs_single_device_topk():
     (float) to single-device serving, with per-mesh wave telemetry."""
     print(_run("""
         import numpy as np, jax
+        from repro.launch.mesh import make_mesh
         from repro.graphs import holme_kim_powerlaw
         from repro.ppr_serving import (PPRQuery, PPRService, RegisteredGraph,
                                        ShardedRegisteredGraph)
 
         g = holme_kim_powerlaw(601, m=5, seed=2)       # 601 % 4 != 0
-        mesh = jax.make_mesh((4,), ("shard",))
+        mesh = make_mesh((4,), ("shard",))
         verts = np.random.default_rng(0).integers(0, g.num_vertices, 8)
 
         def serve(mesh_arg):
@@ -180,6 +182,7 @@ def test_fixed_engine_vs_sharded_fixed_engine_raw_uint32_equality():
     datapath."""
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.core.fixed_point import Q1_25
         from repro.graphs import holme_kim_powerlaw
         from repro.ppr_serving import PPRService, engine_for
@@ -188,7 +191,7 @@ def test_fixed_engine_vs_sharded_fixed_engine_raw_uint32_equality():
 
         V = 389                                        # prime: no shard count divides it
         g = holme_kim_powerlaw(V, m=4, seed=3)
-        mesh = jax.make_mesh((8,), ("shard",))
+        mesh = make_mesh((8,), ("shard",))
         rg_single = RegisteredGraph("g", g)
         rg_sharded = ShardedRegisteredGraph("g", g, mesh)
         fixed = engine_for("single", True)
@@ -224,12 +227,13 @@ def test_sharded_graph_pre_quantizes_shards_and_purges_on_reregister():
     wave keys must keep the name-prefix purge working)."""
     print(_run("""
         import jax
+        from repro.launch.mesh import make_mesh
         from repro.core.fixed_point import Q1_25
         from repro.graphs import erdos_renyi
         from repro.ppr_serving import PPRQuery, PPRService
 
         g = erdos_renyi(203, 1500, seed=1)             # 203 % 4 != 0
-        mesh = jax.make_mesh((4,), ("shard",))
+        mesh = make_mesh((4,), ("shard",))
         svc = PPRService(kappa=8, iterations=5)
         rg = svc.register_graph("g", g, formats=[26], mesh=mesh)
         assert Q1_25 in rg._sharded_quantized          # pre-partitioned at registration
