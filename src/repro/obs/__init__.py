@@ -11,7 +11,9 @@ is the time-resolved counterpart, with memory O(1) in queries served:
 ``trace.py``     span-based tracer with injected clocks: every query carries
                  a trace (submit → cache probe → admission wait → wave
                  execute → resolution) cross-linked with a per-wave trace
-                 (plan → iterate w/ early-exit residual → top-K → resolve).
+                 (plan → iterate w/ early-exit residual → top-K → resolve);
+                 ``stage()`` times a layer boundary once for telemetry, the
+                 wave trace and the ``jax.profiler`` device profile.
 ``recorder.py``  flight recorder: ring buffers of the last N completed
                  traces and admission-control transitions, so a shed/degrade
                  incident can be reconstructed after the fact.
@@ -27,7 +29,8 @@ is the time-resolved counterpart, with memory O(1) in queries served:
                  periodic delta-temporality push.
 
 Everything is clock-injected and deterministic under test; nothing here
-imports jax — the observability layer must never be the thing that makes
+imports jax at import time (``stage()`` loads ``jax.profiler`` on first
+use) — the observability layer must never be the thing that makes
 the hot path slow or the test suite heavy.
 """
 from repro.obs.export import (
@@ -47,12 +50,12 @@ from repro.obs.metrics import (
 from repro.obs.otlp import OTLPExporter
 from repro.obs.recorder import FlightRecorder
 from repro.obs.slo import SLOMonitor, SLOSpec, default_slo_specs
-from repro.obs.trace import Span, Trace, Tracer, fanout_sink
+from repro.obs.trace import Span, Trace, Tracer, fanout_sink, stage
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Reservoir", "MetricsRegistry",
     "exponential_buckets",
-    "Span", "Trace", "Tracer", "fanout_sink",
+    "Span", "Trace", "Tracer", "fanout_sink", "stage",
     "FlightRecorder",
     "SLOSpec", "SLOMonitor", "default_slo_specs",
     "OTLPExporter",

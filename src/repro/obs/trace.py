@@ -20,15 +20,23 @@ traces with a fake clock and assert whole span trees deterministically.
 The tracer itself holds no history — completed traces go to a sink (the
 flight recorder); a tracing-off service simply has no tracer and pays only
 an ``is None`` check per instrumentation point.
+
+``stage`` is the one way a layer boundary is timed: it opens a
+``jax.profiler.TraceAnnotation`` (a host event on the device profile's
+clock whenever a profiler runs, about a microsecond when none does) and,
+given a clock, times the same stretch for the stage telemetry and the
+wave trace.  Stage names are fixed ``ppr.``-prefixed strings; a stage never
+encloses an ``await``, so stages nest per thread even on the event loop.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, Iterator, List, Optional
 
-__all__ = ["Span", "Trace", "Tracer", "fanout_sink"]
+__all__ = ["Span", "Trace", "Tracer", "fanout_sink", "stage"]
 
 
 def fanout_sink(*sinks: Callable[["Trace"], None]
@@ -139,3 +147,25 @@ class Tracer:
         if self.sink is not None:
             self.sink(trace)
         return trace
+
+
+@contextlib.contextmanager
+def stage(name: str, clock: Optional[Callable[[], float]] = None,
+          sink: Optional[Callable[[Span], None]] = None,
+          **attrs: Any) -> Iterator[Span]:
+    """Time one stage under a profiler annotation of the same name.
+
+    Yields a ``Span`` the body may add ``attrs`` to.  With a ``clock``, the
+    span's start and end are read from it at entry and exit, and on exit the
+    finished span goes to ``sink``; without one the stage is a profiler
+    annotation only and reads no clock.  A stage whose body raises reaches
+    no sink."""
+    from jax.profiler import TraceAnnotation  # repro.obs imports no jax
+
+    with TraceAnnotation(name):
+        sp = Span(name, 0.0 if clock is None else clock(), attrs=attrs)
+        yield sp
+        if clock is not None:
+            sp.end_s = clock()
+            if sink is not None:
+                sink(sp)

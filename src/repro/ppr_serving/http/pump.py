@@ -32,6 +32,8 @@ import asyncio
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
+from repro.obs import stage
+
 __all__ = ["WavePump"]
 
 
@@ -83,6 +85,10 @@ class WavePump:
         return await asyncio.get_running_loop().run_in_executor(
             self._executor, fn)
 
+    def _poll(self) -> int:
+        with stage("ppr.pump.poll"):
+            return self.service.poll()
+
     async def stop(self) -> None:
         """Cancel the heartbeat, then flush: every admitted future resolves
         (shutdown must not leak pending futures — in-flight HTTP handlers
@@ -116,13 +122,15 @@ class WavePump:
             self.cycles += 1
             if self._cycles_metric is not None:
                 self._cycles_metric.get().inc()
-            if self.admission is not None:
-                self.admission.tick()
-            elif getattr(self.service, "slo", None) is not None:
-                # no admission controller to carry the monitor: evaluate the
-                # SLOs on the heartbeat anyway (alerting without the ladder)
-                self.service.slo.tick()
-            launched = await self._drive(self.service.poll)
+            with stage("ppr.pump.tick"):
+                if self.admission is not None:
+                    self.admission.tick()
+                elif getattr(self.service, "slo", None) is not None:
+                    # no admission controller to carry the monitor: evaluate
+                    # the SLOs on the heartbeat anyway (alerting without the
+                    # ladder)
+                    self.service.slo.tick()
+            launched = await self._drive(self._poll)
             self.waves_launched += launched
             if self._waves_metric is not None and launched:
                 self._waves_metric.get().inc(launched)

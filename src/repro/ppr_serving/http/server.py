@@ -35,10 +35,10 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 from urllib.parse import parse_qs
 
-from repro.obs import prometheus_text
+from repro.obs import prometheus_text, stage
 from repro.ppr_serving.futures import QueryRejected
 from repro.ppr_serving.http.admission import AdmissionConfig, AdmissionController
 from repro.ppr_serving.http.pump import WavePump
@@ -77,6 +77,8 @@ class HTTPResponse:
     # and its content type; ``payload`` then goes unrendered
     body: Optional[bytes] = None
     content_type: str = "application/json"
+    # called by the transport once the response is written and drained
+    on_sent: Optional[Callable[[], None]] = None
 
 
 class ServingApp:
@@ -122,58 +124,73 @@ class ServingApp:
 
     # ------------------------------------------------------------------
     async def _handle_ppr(self, req: HTTPRequest) -> HTTPResponse:
+        clock = self.service.time_fn
+        with stage("ppr.http.parse_submit", clock) as parse_submit:
+            try:
+                spec = PPRRequestSchema.parse(req.body)
+            except SchemaError as e:
+                return HTTPResponse(400, error_payload(str(e), "bad-request"))
+
+            if self.admission is not None:
+                retry_after = self.admission.admit(graph=spec.graph)
+                if retry_after is not None:
+                    return HTTPResponse(
+                        429,
+                        error_payload(
+                            "admission queue is over its high-water mark — "
+                            "load shed; retry after the hinted backoff",
+                            "shed", retry_after_s=retry_after),
+                        headers=(("Retry-After", f"{retry_after:.3f}"),))
+
+            # the degradation decision the response reports: taken at submit
+            # time, when resolution happens — not when the wave later runs
+            ceiling = self.service.controller.target_ceiling
+            degraded = False
+            if spec.precision == AUTO_KEY and ceiling is not None:
+                requested = (self.service.controller.config.default_target
+                             if spec.quality_target is None
+                             else float(spec.quality_target))
+                degraded = ceiling < requested
+
+            q = PPRQuery(graph=spec.graph, vertex=spec.vertex, k=spec.k,
+                         precision=spec.precision,
+                         quality_target=spec.quality_target,
+                         deadline=spec.deadline_s)
+            try:
+                fut = self.service.submit(q)
+            except KeyError as e:
+                return HTTPResponse(404, error_payload(
+                    str(e).strip('"\''), "unknown-graph"))
+            except ValueError as e:
+                return HTTPResponse(400, error_payload(str(e), "bad-request"))
+
         try:
-            spec = PPRRequestSchema.parse(req.body)
-        except SchemaError as e:
-            return HTTPResponse(400, error_payload(str(e), "bad-request"))
-
-        if self.admission is not None:
-            retry_after = self.admission.admit(graph=spec.graph)
-            if retry_after is not None:
-                return HTTPResponse(
-                    429,
-                    error_payload(
-                        "admission queue is over its high-water mark — load "
-                        "shed; retry after the hinted backoff",
-                        "shed", retry_after_s=retry_after),
-                    headers=(("Retry-After", f"{retry_after:.3f}"),))
-
-        # the degradation decision the response reports: taken at submit
-        # time, when resolution happens — not when the wave later runs
-        ceiling = self.service.controller.target_ceiling
-        degraded = False
-        if spec.precision == AUTO_KEY and ceiling is not None:
-            requested = (self.service.controller.config.default_target
-                         if spec.quality_target is None
-                         else float(spec.quality_target))
-            degraded = ceiling < requested
-
-        q = PPRQuery(graph=spec.graph, vertex=spec.vertex, k=spec.k,
-                     precision=spec.precision,
-                     quality_target=spec.quality_target,
-                     deadline=spec.deadline_s)
-        try:
-            fut = self.service.submit(q)
-        except KeyError as e:
-            return HTTPResponse(404, error_payload(
-                str(e).strip('"\''), "unknown-graph"))
-        except ValueError as e:
-            return HTTPResponse(400, error_payload(str(e), "bad-request"))
-
-        try:
-            rec = await self._await_future(fut)
+            rec, resolved_at = await self._await_future(fut)
         except QueryRejected as e:
             status = _REJECT_STATUS.get(e.code, 409)
             return HTTPResponse(status, error_payload(str(e), e.code))
-        return HTTPResponse(200, recommendation_payload(rec, degraded=degraded))
+        telemetry = self.service.telemetry
+
+        def sent() -> None:
+            # the HTTP layer's own time: its part before the queue, and its
+            # part after the wave (loop hand-off, payload, write, drain)
+            telemetry.record_http_self(parse_submit.duration_s
+                                       + clock() - resolved_at)
+
+        return HTTPResponse(200, recommendation_payload(rec, degraded),
+                            on_sent=sent)
 
     async def _await_future(self, fut):
         """Bridge a ``PPRFuture`` into the event loop: the pump resolves it
-        from its poll cycles; this handler just parks until then."""
+        from its poll cycles; this handler just parks until then.  Returns
+        the result and when the future resolved, on the service's clock."""
         loop = asyncio.get_running_loop()
         af: asyncio.Future = loop.create_future()
+        clock = self.service.time_fn
 
         def _done(f) -> None:
+            resolved_at = clock()
+
             def _transfer() -> None:
                 if af.cancelled():
                     f.exception()      # consume: a gone client must not leak
@@ -182,7 +199,7 @@ class ServingApp:
                 if exc is not None:
                     af.set_exception(exc)
                 else:
-                    af.set_result(f.result())
+                    af.set_result((f.result(), resolved_at))
             # resolution happens inside pump/handler code already on this
             # loop, but threadsafe scheduling keeps an engine-thread future
             # resolution (a later offload) from corrupting the loop
@@ -310,8 +327,11 @@ class AsyncioHTTPTransport:
                 except Exception as e:   # a handler bug must answer, not hang
                     resp = HTTPResponse(500, error_payload(
                         f"internal error: {e!r}", "internal"))
-                self._write_response(writer, resp)
+                with stage("ppr.http.respond"):
+                    self._write_response(writer, resp)
                 await writer.drain()
+                if resp.on_sent is not None:
+                    resp.on_sent()
                 if req.headers.get("connection", "").lower() == "close":
                     break
         except (ConnectionResetError, asyncio.IncompleteReadError):

@@ -42,6 +42,7 @@ import time
 import warnings
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -51,7 +52,7 @@ from repro.core.fixed_point import PAPER_FORMATS, QFormat, format_for_bits
 from repro.core.metrics import ranking
 from repro.graph_updates.delta import EdgeDelta
 from repro.graph_updates.warmstart import WarmStartStore
-from repro.obs import FlightRecorder, Tracer, fanout_sink
+from repro.obs import FlightRecorder, Span, Tracer, fanout_sink, stage
 from repro.obs.otlp import OTLPExporter
 from repro.obs.slo import SLOMonitor, SLOSpec, default_slo_specs
 from repro.ppr_serving.cache import LRUCache
@@ -821,11 +822,13 @@ class PPRService:
             self.tracer.finish(fut._trace, outcome="rejected", code=code)
             fut._trace = None
 
+    @stage("ppr.wave")
     def _run_wave(self, wave: Wave) -> List[Recommendation]:
         graph_name, pkey, mesh_key, _epoch = wave.key
         rg = self._graphs[graph_name]
         fmt = None if pkey == FLOAT_KEY else normalize_precision(pkey)
-        t0 = self.time_fn()
+        clock = self.time_fn
+        t0 = clock()
 
         # deadline-aware shed (before any compute is spent): a query whose
         # admission wait already exceeds its deadline gets a prompt 504, not
@@ -879,58 +882,75 @@ class PPRService:
         for enq in wave.enqueued_at:
             self.telemetry.record_admission_wait(max(0.0, t0 - enq))
 
-        # the graph's engine family decides how its waves iterate; arming
-        # keeps late-bound engines in the delta device-refresh loop
-        engine = engine_for(rg.engine_family, fmt is not None)
-        rg.arm(engine)
-        plan = engine.plan(rg, fmt, alpha=self.alpha,
-                           iterations=self.iterations,
-                           convergence=self.convergence,
-                           topk_tile=self.topk_tile,
-                           trace_hook=iterate_info.update
-                           if tracer is not None else None)
+        def record(sp: Span) -> None:   # one interval, every stage reader
+            name = sp.name.rpartition(".")[2]
+            self.telemetry.record_stage(name, sp.duration_s)
+            if wtr is not None:   # shares sp.attrs, which later stages amend
+                wtr.root.children.append(dataclasses.replace(sp, name=name))
 
-        queries = [fut.query for fut in wave.items]
-        verts = [int(q.vertex) for q in queries]
-        pad = self.kappa - len(verts)
-        padded = verts + [verts[0]] * pad           # pad columns are discarded
-        pers = jnp.asarray(np.asarray(padded, np.int32))
+        with stage("ppr.wave.plan", clock, record) as sp:
+            # the graph's engine family decides how its waves iterate;
+            # arming keeps late-bound engines in the delta device-refresh loop
+            engine = engine_for(rg.engine_family, fmt is not None)
+            rg.arm(engine)
+            plan = engine.plan(rg, fmt, alpha=self.alpha,
+                               iterations=self.iterations,
+                               convergence=self.convergence,
+                               topk_tile=self.topk_tile,
+                               trace_hook=iterate_info.update
+                               if tracer is not None else None)
+            sp.attrs["engine"] = plan.engine
+            queries = [fut.query for fut in wave.items]
+            verts = [int(q.vertex) for q in queries]
+            pad = self.kappa - len(verts)
+            padded = verts + [verts[0]] * pad       # pad columns are discarded
+            pers = jnp.asarray(np.asarray(padded, np.int32))
+            Vmat = plan.initial(pers)
 
-        Vmat = plan.initial(pers)
-        t_plan = self.time_fn()
-        self.telemetry.record_stage("plan", t_plan - t0)
-        P0, warm_cols = (self._warm_seed(rg, wave, pkey, Vmat)
-                         if self._warm is not None else (Vmat, 0))
-        t_warm = self.time_fn()
-        self.telemetry.record_stage("warm_start", t_warm - t_plan)
-        P, iters_run = plan.iterate(lambda P_: plan.step(Vmat, P_), P0)
-        if iters_run < self.iterations:
-            self.telemetry.record_early_exit(self.iterations - iters_run)
-        self.telemetry.record_wave_iterations(iters_run)
-        warm_saved = 0
-        if self._warm is not None:
-            P_host = np.asarray(P)
-            for col, q in enumerate(queries):
-                self._warm.put(graph_name, int(q.vertex), pkey,
-                               P_host[:, col].copy())
-            if warm_cols:
-                base = self._cold_iters.get((graph_name, pkey))
-                warm_saved = max(0, base - iters_run) if base is not None else 0
-                self.telemetry.record_warm_start(warm_cols, warm_saved)
-            else:
-                self._cold_iters[(graph_name, pkey)] = iters_run
-        t_iter = self.time_fn()
-        self.telemetry.record_stage("iterate", t_iter - t_warm)
+        with stage("ppr.wave.warm_start", clock, record) as warm:
+            P0, warm_cols = (self._warm_seed(rg, wave, pkey, Vmat)
+                             if self._warm is not None else (Vmat, 0))
+            warm.attrs["warm_cols"] = warm_cols
+
+        def step(P_):
+            with stage("ppr.wave.step"):
+                return plan.step(Vmat, P_)
+
+        with stage("ppr.wave.iterate", clock, record) as iterate:
+            self.telemetry.record_wave_gap(
+                min(wave.enqueued_at, default=t0), iterate.start_s)
+            P, iters_run = plan.iterate(step, P0)
+            # end on the device, not on the dispatch: else the iterations'
+            # device time lands in whichever stage syncs next (top-K)
+            jax.block_until_ready(P)
+            if iters_run < self.iterations:
+                self.telemetry.record_early_exit(self.iterations - iters_run)
+            self.telemetry.record_wave_iterations(iters_run)
+            warm_saved = 0
+            if self._warm is not None:
+                P_host = np.asarray(P)
+                for col, q in enumerate(queries):
+                    self._warm.put(graph_name, int(q.vertex), pkey,
+                                   P_host[:, col].copy())
+                if warm_cols:
+                    base = self._cold_iters.get((graph_name, pkey))
+                    warm_saved = (max(0, base - iters_run)
+                                  if base is not None else 0)
+                    self.telemetry.record_warm_start(warm_cols, warm_saved)
+                else:
+                    self._cold_iters[(graph_name, pkey)] = iters_run
+            warm.attrs["iterations_saved"] = warm_saved
+            iterate.attrs.update(iterate_info)
 
         k_max = max(q.k for q in queries)
-        idx, vals = plan.topk(P, k_max, pers)
-        idx = np.asarray(idx)                        # [κ, k_max]
-        vals = np.asarray(vals)
-        scores = vals.astype(np.float64) / plan.scale if plan.fixed \
-            else vals.astype(np.float64)
-        t_topk = self.time_fn()
-        self.telemetry.record_stage("topk", t_topk - t_iter)
-        latency = t_topk - t0
+        with stage("ppr.wave.topk", clock, record, k_max=k_max) as topk:
+            idx, vals = plan.topk(P, k_max, pers)
+            idx = np.asarray(idx)                    # [κ, k_max]
+            vals = np.asarray(vals)
+            scores = vals.astype(np.float64) / plan.scale if plan.fixed \
+                else vals.astype(np.float64)
+        self.telemetry.record_topk_end(topk.end_s)
+        latency = topk.end_s - t0
 
         recs = []
         # the cache fill + counters are the wave's shared-state tail: take the
@@ -938,19 +958,18 @@ class PPRService:
         # entry or a complete one (engine compute above ran unlocked — that is
         # the whole point of the pump offload)
         with self._lock:
-            for col, fut in enumerate(wave.items):
-                q = fut.query
-                v_top = idx[col, : q.k].copy()
-                s_top = scores[col, : q.k].copy()
-                # the cache keeps its own copies: callers may mutate their
-                # Recommendation arrays without poisoning later hits
-                self.cache.put(self._cache_key(q, pkey, epoch=_epoch),
-                               (v_top.copy(), s_top.copy()))
-                recs.append(Recommendation(q, v_top, s_top, source="wave",
-                                           wave_id=wave_id, latency_s=latency,
-                                           precision=pkey))
-            t_resolve = self.time_fn()
-            self.telemetry.record_stage("resolve", t_resolve - t_topk)
+            with stage("ppr.wave.resolve", clock, record) as resolve:
+                for col, fut in enumerate(wave.items):
+                    q = fut.query
+                    v_top = idx[col, : q.k].copy()
+                    s_top = scores[col, : q.k].copy()
+                    # the cache keeps its own copies: callers may mutate their
+                    # Recommendation arrays without poisoning later hits
+                    self.cache.put(self._cache_key(q, pkey, epoch=_epoch),
+                                   (v_top.copy(), s_top.copy()))
+                    recs.append(Recommendation(
+                        q, v_top, s_top, source="wave", wave_id=wave_id,
+                        latency_s=latency, precision=pkey))
             # per-occupant end-to-end latency (submit → resolution): the
             # distribution the latency SLO evaluates.  Synthetic prefetch
             # queries are cache warming, not traffic — they don't count.
@@ -959,37 +978,32 @@ class PPRService:
                     enq = (wave.enqueued_at[col]
                            if col < len(wave.enqueued_at) else t0)
                     self.telemetry.record_query_latency(
-                        graph_name, max(0.0, t_resolve - enq))
+                        graph_name, max(0.0, resolve.end_s - enq))
             self.telemetry.record_wave(len(wave.items), self.kappa, latency,
                                        pkey, mesh_key=mesh_key,
                                        engine=plan.engine, graph=graph_name)
-        self._shadow_feedback(wave, rg, fmt, pkey, P)
-        if wtr is not None:
-            wtr.span("plan", t0).end(t_plan, engine=plan.engine)
-            wtr.span("warm_start", t_plan).end(
-                t_warm, warm_cols=warm_cols, iterations_saved=warm_saved)
-            wtr.span("iterate", t_warm).end(t_iter, **iterate_info)
-            wtr.span("topk", t_iter).end(t_topk, k_max=k_max)
-            wtr.span("resolve", t_topk).end(t_resolve)
-            tracer.finish(wtr, latency_s=latency, engine=plan.engine)
-        # resolve futures LAST: with the pump offload a waiter wakes the
-        # moment its future resolves (the loop-thread bridge), and must then
-        # observe the wave's *completed* accounting — counters, traces and
-        # cache fills all land before any caller can see the result
-        for col, fut in enumerate(wave.items):
-            fut._resolve(recs[col])
-            if tracer is not None and fut._trace is not None:
-                tr = fut._trace
-                enq = (wave.enqueued_at[col]
-                       if col < len(wave.enqueued_at) else t0)
-                tr.span("admission_wait", enq).end(t0)
-                tr.span("wave_execute", t0, wave_id=wave_id,
-                        engine=plan.engine,
-                        **iterate_info).end(self.time_fn())
-                tracer.finish(tr, outcome="resolved", source="wave",
-                              precision=pkey,
-                              wave_trace=wtr.trace_id if wtr else None)
-                fut._trace = None
+        with stage("ppr.wave.deliver"):
+            self._shadow_feedback(wave, rg, fmt, pkey, P)
+            if wtr is not None:
+                tracer.finish(wtr, latency_s=latency, engine=plan.engine)
+            # resolve futures LAST: with the pump offload a waiter wakes the
+            # moment its future resolves (the loop-thread bridge), and must
+            # then observe the wave's *completed* accounting — counters,
+            # traces and cache fills all land before any caller can see it
+            for col, fut in enumerate(wave.items):
+                fut._resolve(recs[col])
+                if tracer is not None and fut._trace is not None:
+                    tr = fut._trace
+                    enq = (wave.enqueued_at[col]
+                           if col < len(wave.enqueued_at) else t0)
+                    tr.span("admission_wait", enq).end(t0)
+                    tr.span("wave_execute", t0, wave_id=wave_id,
+                            engine=plan.engine,
+                            **iterate_info).end(clock())
+                    tracer.finish(tr, outcome="resolved", source="wave",
+                                  precision=pkey,
+                                  wave_trace=wtr.trace_id if wtr else None)
+                    fut._trace = None
         return recs
 
     # ------------------------------------------------------------------

@@ -35,7 +35,11 @@ Per-stage wave timing (``record_stage``: plan / warm_start / iterate / topk
 / resolve, plus the pre-wave admission wait) is what finally says *where* a
 query's milliseconds went rather than just how many there were — the
 breakdown feeds ``summary()``'s ``stage_*`` keys, the bench JSON rows, and
-``/v1/metrics``.
+``/v1/metrics``.  The ``iterate`` stage ends on a device sync, so its time
+is the iterations' device time and not their dispatch.  Two host stretches
+are counted beside the stages: the wave gap (one wave's top-K on the host
+to the next wave's first iteration, while a query waited) and the HTTP
+layer's own time per answered request.
 """
 from __future__ import annotations
 
@@ -125,6 +129,22 @@ class ServiceTelemetry:
         self._wave_iterations = r.histogram(
             "ppr_wave_iterations", "Iterations actually run per wave.",
             bounds=_ITER_BUCKETS)
+        # the host stretch that keeps the device idle while work waits:
+        # one wave's top-K on the host to the next wave's first iteration
+        self._wave_gap = r.histogram(
+            "ppr_wave_gap_seconds",
+            "Previous wave's top-K end to this wave's iterate start, for "
+            "waves whose oldest member waited across the previous wave.")
+        self._wave_gap_q = r.reservoir(
+            "ppr_wave_gap_seconds_quantiles", "Wave-gap percentile sample.")
+        self._last_topk_end_s: Optional[float] = None
+        self._http_self = r.histogram(
+            "ppr_http_self_seconds",
+            "HTTP layer's own time per 200 POST /v1/ppr: request read to "
+            "submit returned, plus future resolved to response drained.")
+        self._http_self_q = r.reservoir(
+            "ppr_http_self_seconds_quantiles",
+            "HTTP self-time percentile sample.")
         # -- adaptive-precision subsystem (repro.autotune) -------------------
         self._auto_resolved = r.counter(
             "ppr_auto_resolved_total",
@@ -252,6 +272,29 @@ class ServiceTelemetry:
         """One query's submit → wave-launch queue time."""
         self._admission_wait.get().observe(seconds)
         self._admission_wait_q.get().add(seconds)
+
+    def record_topk_end(self, t: float) -> None:
+        """A wave's top-K reached the host at ``t`` (the ``topk`` stage
+        end): the start of the next wave's gap."""
+        self._last_topk_end_s = t
+
+    def record_wave_gap(self, oldest_enqueued_s: float,
+                        iterate_start_s: float) -> None:
+        """A wave's iterate stage started at ``iterate_start_s``.  When its
+        oldest member was enqueued before the previous wave's top-K end, the
+        time between that end and this start was host work while a query
+        waited; the first wave after a reset has no previous end."""
+        last = self._last_topk_end_s
+        if last is not None and oldest_enqueued_s < last:
+            gap = max(0.0, iterate_start_s - last)
+            self._wave_gap.get().observe(gap)
+            self._wave_gap_q.get().add(gap)
+
+    def record_http_self(self, seconds: float) -> None:
+        """One 200 ``POST /v1/ppr``'s time in the HTTP layer itself, queue
+        wait and wave excluded."""
+        self._http_self.get().observe(seconds)
+        self._http_self_q.get().add(seconds)
 
     def record_wave_iterations(self, n: int) -> None:
         """Iterations one wave actually ran (early exit shortens this)."""
