@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +27,7 @@ import numpy as np
 from repro.core.coo import COOGraph
 from repro.core.fixed_point import QFormat
 from repro.core.spmv import (
+    SortedDst,
     make_sharded_spmv,
     make_sharded_spmv_fixed,
     spmv_fixed,
@@ -129,11 +130,15 @@ def ppr_step_float(
 
 @functools.lru_cache(maxsize=64)
 def make_ppr_fixed_step(fmt: QFormat, num_vertices: int, alpha: float):
-    """Jitted bit-exact single iteration in the raw uint32 domain of ``fmt``."""
+    """Jitted bit-exact single iteration in the raw uint32 domain of ``fmt``.
+
+    ``x`` is the stream's destinations, or a ``SortedDst`` that adds its
+    row pointers: the SpMV then reduces by row prefix, not by scatter, to
+    the same bits (``spmv_fixed``)."""
     a_raw, oma_raw, aov_raw = _fixed_consts(fmt, num_vertices, alpha)
 
     @jax.jit
-    def step(x: Array, y: Array, val_raw: Array, dangling: Array,
+    def step(x: Union[Array, SortedDst], y: Array, val_raw: Array, dangling: Array,
              Vmat: Array, P: Array) -> Array:
         return _fixed_iteration(
             x, y, val_raw, dangling.astype(jnp.uint32), Vmat, P,

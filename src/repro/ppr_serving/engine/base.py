@@ -53,6 +53,10 @@ class WavePlan:
                  with a convergence policy.
     ``topk``     (P, k_max, exclude) → (idx [κ, k], vals [κ, k]) ranked with
                  the query vertex excluded.
+    ``reduce``   how the SpMV sums each destination row (telemetry label):
+                 ``"row_prefix"`` (prefix sum read at the dst-sorted stream's
+                 row boundaries), ``"scatter"`` (segment-sum) or ``"fused"``
+                 (in the Pallas kernel's accumulator).
     """
     engine: str
     fixed: bool
@@ -61,6 +65,7 @@ class WavePlan:
     step: Callable[[Any, Any], Any]
     iterate: Callable[[Callable[[Any], Any], Any], Tuple[Any, int]]
     topk: Callable[[Any, int, Optional[Any]], Tuple[Any, Any]]
+    reduce: str
 
 
 class WaveEngine(abc.ABC):

@@ -64,6 +64,7 @@ class PallasRegisteredGraph(RegisteredGraph):
     engine_family = "pallas"
 
     _defer_full_upload = True
+    _edge_align = 1           # fused waves read their own layout
 
     def __init__(self, name: str, g: COOGraph, packet: int = 256,
                  v_tile: int = DEFAULT_V_TILE):
@@ -291,7 +292,7 @@ class PallasFloatEngine(WaveEngine):
             step=_bind_fused_step(rg, None, alpha, cell),
             iterate=_make_fused_iterate(self, iterations, convergence, False,
                                         None, cell, trace_hook=trace_hook),
-            topk=self._make_topk(topk_tile))
+            topk=self._make_topk(topk_tile), reduce="fused")
 
     def on_delta(self, rg, info) -> None:
         rg.refresh_device_base()
@@ -332,7 +333,7 @@ class PallasFixedEngine(WaveEngine):
             iterate=_make_fused_iterate(self, iterations, convergence, True,
                                         fmt.scale, cell,
                                         trace_hook=trace_hook),
-            topk=self._make_topk(topk_tile))
+            topk=self._make_topk(topk_tile), reduce="fused")
 
     def on_delta(self, rg, info) -> None:
         rg.refresh_device_base()

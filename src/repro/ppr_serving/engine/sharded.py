@@ -153,7 +153,7 @@ class ShardedFloatEngine(WaveEngine):
             step=step,
             iterate=self._make_iterate(iterations, convergence, False, None,
                                        trace_hook=trace_hook),
-            topk=self._make_topk(topk_tile))
+            topk=self._make_topk(topk_tile), reduce="scatter")
 
     def on_delta(self, rg, info) -> None:
         rg.refresh_device_base()
@@ -199,7 +199,7 @@ class ShardedFixedEngine(WaveEngine):
             step=step,
             iterate=self._make_iterate(iterations, convergence, True, fmt.scale,
                                        trace_hook=trace_hook),
-            topk=self._make_topk(topk_tile))
+            topk=self._make_topk(topk_tile), reduce="scatter")
 
     def on_delta(self, rg, info) -> None:
         rg.refresh_device_base()

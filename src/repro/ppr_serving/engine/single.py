@@ -49,7 +49,7 @@ class FloatEngine(WaveEngine):
             step=step,
             iterate=self._make_iterate(iterations, convergence, False, None,
                                        trace_hook=trace_hook),
-            topk=self._make_topk(topk_tile))
+            topk=self._make_topk(topk_tile), reduce="scatter")
 
     def on_delta(self, rg, info) -> None:
         rg.refresh_device_base()
@@ -67,6 +67,7 @@ class FixedEngine(WaveEngine):
         if fmt is None:
             raise ValueError(f"{self.key!r} engine needs a concrete Q format")
         rg.quantized(fmt)
+        rg.device_rows()
 
     def plan(self, rg, fmt: Optional[QFormat] = None, *, alpha: float,
              iterations: int, convergence=None,
@@ -74,7 +75,8 @@ class FixedEngine(WaveEngine):
         if fmt is None:
             raise ValueError(f"{self.key!r} engine needs a concrete Q format")
         body = make_ppr_fixed_step(fmt, rg.num_vertices, alpha)
-        x, y, _ = rg.device_full()
+        _, y, _ = rg.device_full()
+        x = rg.device_rows()     # a SortedDst: the step reduces by row prefix
         val_raw = rg.quantized(fmt)
         dangling = rg.dangling
         num_vertices = rg.num_vertices
@@ -89,7 +91,7 @@ class FixedEngine(WaveEngine):
             step=step,
             iterate=self._make_iterate(iterations, convergence, True, fmt.scale,
                                        trace_hook=trace_hook),
-            topk=self._make_topk(topk_tile))
+            topk=self._make_topk(topk_tile), reduce="row_prefix")
 
     def on_delta(self, rg, info) -> None:
         rg.refresh_device_base()

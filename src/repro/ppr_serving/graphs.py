@@ -16,6 +16,7 @@ keys and wave keys so results computed on different topologies never alias.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax.numpy as jnp
@@ -23,7 +24,7 @@ import numpy as np
 
 from repro.core.coo import COOGraph, EdgeMergeInfo, quantize_values
 from repro.core.fixed_point import QFormat
-from repro.core.spmv import sharded_vertex_layout
+from repro.core.spmv import ROW_PREFIX_ALIGN, SortedDst, sharded_vertex_layout
 from repro.graph_updates.delta import EdgeDelta
 from repro.ppr_serving.telemetry import SINGLE_DEVICE_KEY
 
@@ -44,17 +45,23 @@ class RegisteredGraph:
     engine_family = "single"
 
     _defer_full_upload = False
+    # the fixed engine reduces the full stream by row prefix, and the float
+    # engine reads the same device copy: both get whole row-prefix tiles
+    _edge_align = ROW_PREFIX_ALIGN
 
     def __init__(self, name: str, g: COOGraph, packet: int = 256):
         self.name = name
         self.source = g                      # unpadded host graph (delta base)
         self.packet = packet
         self.epoch = 0
-        self.graph = g.pad_to_packets(packet)
+        self._pad_to = math.lcm(packet, self._edge_align)
+        self.graph = g.pad_to_packets(self._pad_to)
         self.num_vertices = g.num_vertices
         self.dangling = jnp.asarray(self.graph.dangling)
         self._outdeg = np.bincount(g.y, minlength=g.num_vertices).astype(np.int64)
         self._full_device: Optional[Tuple[jnp.ndarray, ...]] = None
+        self._rows = None                     # device_rows() cache
+        self._rows_were_materialized = False
         self._quantized: Dict[QFormat, jnp.ndarray] = {}
         self._quantized_host: Dict[QFormat, np.ndarray] = {}   # unpadded uint32
         self._stale_device_formats: set = set()
@@ -80,6 +87,25 @@ class RegisteredGraph:
                                  jnp.asarray(self.graph.y),
                                  jnp.asarray(self.graph.val))
         return self._full_device
+
+    def device_rows(self) -> SortedDst:
+        """The full-layout ``x`` with the row pointers of the unpadded
+        stream, which the fixed-point step reduces by row prefix.
+        ``row_ptr`` [V + 1] int32 is uploaded once per topology epoch.
+
+        Raises ``ValueError`` when the stream is not sorted by destination
+        (``COOGraph.from_edges`` sorts it, and delta merges keep it so)."""
+        if self._rows is None:
+            sx = self.source.x
+            if np.any(sx[1:] < sx[:-1]):
+                raise ValueError(
+                    f"graph {self.name!r}: the fixed-point engine needs the "
+                    f"edge stream sorted by destination (build it with "
+                    f"COOGraph.from_edges)")
+            self._rows = SortedDst(self.device_full()[0], jnp.asarray(
+                np.searchsorted(sx, np.arange(self.num_vertices + 1))
+                .astype(np.int32)))
+        return self._rows
 
     @property
     def x(self) -> jnp.ndarray:
@@ -126,7 +152,7 @@ class RegisteredGraph:
         new_g, info = delta.apply(self.source, outdeg=self._outdeg)
         self._outdeg = info.new_outdeg
         self.source = new_g
-        self.graph = new_g.pad_to_packets(self.packet)
+        self.graph = new_g.pad_to_packets(self._pad_to)
         self.num_vertices = new_g.num_vertices
         self.dangling = jnp.asarray(self.graph.dangling)
         for fmt, old_raw in list(self._quantized_host.items()):
@@ -140,6 +166,8 @@ class RegisteredGraph:
         self._quantized.clear()
         self._full_was_materialized = self._full_device is not None
         self._full_device = None
+        self._rows_were_materialized |= self._rows is not None
+        self._rows = None
         self.epoch += 1
         return info
 
@@ -152,6 +180,9 @@ class RegisteredGraph:
         self._stale_device_formats.clear()
         if self._full_was_materialized or not self._defer_full_upload:
             self.device_full()
+        if self._rows_were_materialized:
+            self.device_rows()
+            self._rows_were_materialized = False
 
 
 class ShardedRegisteredGraph(RegisteredGraph):
@@ -167,6 +198,7 @@ class ShardedRegisteredGraph(RegisteredGraph):
     engine_family = "sharded"
 
     _defer_full_upload = True
+    _edge_align = 1           # its engines read the shards, never row prefix
 
     def __init__(self, name: str, g: COOGraph, mesh, axis: Optional[str] = None,
                  packet: int = 256):

@@ -900,6 +900,7 @@ class PPRService:
                                trace_hook=iterate_info.update
                                if tracer is not None else None)
             sp.attrs["engine"] = plan.engine
+            sp.attrs["reduce"] = plan.reduce
             queries = [fut.query for fut in wave.items]
             verts = [int(q.vertex) for q in queries]
             pad = self.kappa - len(verts)
@@ -981,7 +982,8 @@ class PPRService:
                         graph_name, max(0.0, resolve.end_s - enq))
             self.telemetry.record_wave(len(wave.items), self.kappa, latency,
                                        pkey, mesh_key=mesh_key,
-                                       engine=plan.engine, graph=graph_name)
+                                       engine=plan.engine, graph=graph_name,
+                                       reduce=plan.reduce)
         with stage("ppr.wave.deliver"):
             self._shadow_feedback(wave, rg, fmt, pkey, P)
             if wtr is not None:

@@ -113,6 +113,10 @@ class ServiceTelemetry:
         self._queries_by_mesh = r.counter(
             "ppr_mesh_queries_total", "Queries per mesh layout.",
             labels=("mesh",))
+        self._reduce_waves = r.counter(
+            "ppr_spmv_reduce_waves_total",
+            "Waves per SpMV row reduction (row_prefix/scatter/fused).",
+            labels=("reduce",))
         # bounded precision-history ring (legacy `wave_precisions` list)
         self._wave_precisions = deque(maxlen=self.reservoir_size)
         # -- per-stage wave timing + admission wait -------------------------
@@ -245,7 +249,10 @@ class ServiceTelemetry:
     def record_wave(self, n_queries: int, kappa: int, latency_s: float,
                     precision: str, mesh_key: str = SINGLE_DEVICE_KEY,
                     engine: Optional[str] = None,
-                    graph: str = UNATTRIBUTED) -> None:
+                    graph: str = UNATTRIBUTED,
+                    reduce: Optional[str] = None) -> None:
+        if reduce is not None:
+            self._reduce_waves.labels(reduce=reduce).inc()
         if engine is not None:
             self._engine_latency.labels(engine=engine).observe(latency_s)
             self._engine_latency_q.labels(engine=engine).add(latency_s)

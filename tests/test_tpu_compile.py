@@ -15,6 +15,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.fixed_point import Q1_25
 from repro.core.ppr import make_ppr_fixed_step
+from repro.core.spmv import SortedDst
 from repro.kernels.coo_spmv import MAX_LAUNCH_STEPS, ROW_BLOCK, coo_spmv_pallas
 from repro.kernels.fused_ppr import fused_ppr_iteration
 
@@ -84,14 +85,26 @@ def _composed_paper_1m(spec, fmt):
                       spec((v, KAPPA), jnp.uint32), spec((v, KAPPA), jnp.uint32))
 
 
+def _composed_row_prefix_1m(spec, fmt):
+    """The same step on a dst-sorted stream: ``SortedDst`` makes its
+    reduction a prefix sum read at the row pointers."""
+    v, e = 1 << 20, 16 << 20
+    step = make_ppr_fixed_step(fmt, v, 0.85)
+    rows = SortedDst(spec((e,), jnp.int32), spec((v + 1,), jnp.int32))
+    return step.lower(rows, spec((e,), jnp.int32),
+                      spec((e,), jnp.uint32), spec((v,), jnp.bool_),
+                      spec((v, KAPPA), jnp.uint32), spec((v, KAPPA), jnp.uint32))
+
+
 @pytest.mark.parametrize("build,fmt,kernel", [
     (_fused, None, True),
     (_fused, Q1_25, True),
     (_coo, None, True),
     (_coo, Q1_25, True),
     (_composed_paper_1m, Q1_25, False),
+    (_composed_row_prefix_1m, Q1_25, False),
 ], ids=["fused-f32", "fused-q1.25", "coo-f32", "coo-q1.25",
-        "composed-q1.25-2^20"])
+        "composed-q1.25-2^20", "composed-row-prefix-q1.25-2^20"])
 def test_compiles_for_v5e(one_chip, build, fmt, kernel):
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
