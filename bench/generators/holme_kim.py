@@ -20,8 +20,18 @@ from __future__ import annotations
 
 import numpy as np
 
+# the size a CPU rehearsal of a cell on this generator runs at
+REHEARSAL = {"num_vertices": 3000}
 _CHUNK = 1 << 20
 _TRIES = 8      # rejection draws of a triad neighbour before listing them all
+
+
+def sizes(params: dict):
+    """``(num_vertices, arcs)``, the arcs an upper bound: ``m`` edges from
+    each arriving vertex, both ways.  The graph is made on the host, so
+    there is no ``device_program``."""
+    n, m = int(params["num_vertices"]), int(params["m"])
+    return n, 2 * m * (n - m)
 
 
 def generate(params: dict, seed: int):

@@ -15,6 +15,9 @@ import functools
 
 import numpy as np
 
+# the size a CPU rehearsal of a cell on this generator runs at
+REHEARSAL = {"scale": 9}
+
 
 def _kronecker_device(key, *, scale: int, edge_factor: int, a: float,
                       b: float, c: float):
@@ -54,6 +57,23 @@ def _compiled(scale: int, edge_factor: int, a: float, b: float, c: float):
 
     return jax.jit(functools.partial(_kronecker_device, scale=scale,
                                      edge_factor=edge_factor, a=a, b=b, c=c))
+
+
+def sizes(params: dict):
+    """``(num_vertices, arcs)``, the arcs an upper bound: every generated
+    tuple both ways, before loops and repeats are removed."""
+    n = 1 << int(params["scale"])
+    return n, 2 * int(params["edge_factor"]) * n
+
+
+def device_program(params: dict):
+    """The jitted program ``generate`` runs on the device, and the shapes
+    of its arguments."""
+    import jax
+
+    a, b, c = (float(x) for x in params["initiator"])
+    fn = _compiled(int(params["scale"]), int(params["edge_factor"]), a, b, c)
+    return fn, (jax.ShapeDtypeStruct((), jax.random.key(0).dtype),)
 
 
 def generate(params: dict, seed: int):

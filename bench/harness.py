@@ -3,7 +3,8 @@ and the metrics, as ``run.py`` prints them.
 
 Set-up makes the cell's graph from the seed with the configuration's
 generator, hands it to the program (``COOGraph.from_edges``,
-``PPRService.register_graph``), answers the warm-up and prefill queries that
+``PPRService.register_graph``, on a mesh of the cell's chips where it asks
+for more than one: ``cell_mesh``), answers the warm-up and prefill queries that
 compile the cell's wave shapes (and fill the result cache where the traffic
 says so), resets the service's telemetry and opens the server and the
 keep-alive client connections.  ``setup_s`` ends there.  The window then
@@ -20,11 +21,9 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
-import faulthandler
 import gc
 import shutil
 import statistics
-import sys
 import tempfile
 import time
 from pathlib import Path
@@ -38,7 +37,6 @@ from bench.traffic import make_schedule
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
                   "/jax/core/compile/backend_compile_duration")
 GIVE_UP_S = 60.0        # an open-loop answer later than this past the last send never came
-STALL_S = 1.0           # an event loop held this long gets every thread's stack dumped
 
 
 class NoChip(RuntimeError):
@@ -55,6 +53,7 @@ class Context:
     setup_s: float
     num_vertices: int
     num_edges: int
+    edge_shards: int                 # devices the edge stream is split over
     kappa: int
     iterations: int
     registry: object                 # the service's telemetry registry
@@ -67,6 +66,14 @@ class Context:
             if fam == name:
                 return [inst for _labels, inst in series]
         return []
+
+    def sample_median_ms(self, name: str) -> Optional[float]:
+        """The median of a percentile-sample family's values, in ms; None
+        when it holds none."""
+        import numpy as np
+
+        values = [v for inst in self.family(name) for v in inst.values()]
+        return float(np.percentile(values, 50)) * 1e3 if values else None
 
 
 class _CompileCounter:
@@ -90,12 +97,12 @@ class _CompileCounter:
 
 class _LoopWatch:
     """Watches the window's event loop: the longest it was held past a
-    wake-up, and the longest garbage collection.  Where the loop is held
-    ``STALL_S`` or more, ``faulthandler`` (a thread of its own that needs no
-    interpreter lock) writes every thread's stack to standard error, which
-    names what held it."""
+    wake-up, and the longest garbage collection.  It starts no thread (a
+    ``faulthandler`` watchdog re-armed on the loop would join its old thread
+    and start a new one four times a second, holding the interpreter
+    lock)."""
 
-    PERIOD_S = STALL_S / 4
+    PERIOD_S = 0.25
 
     def __init__(self):
         self.max_lag_s = 0.0
@@ -112,7 +119,6 @@ class _LoopWatch:
 
     async def _run(self) -> None:
         while True:
-            faulthandler.dump_traceback_later(STALL_S, file=sys.stderr)
             t = time.perf_counter()
             await asyncio.sleep(self.PERIOD_S)
             self.max_lag_s = max(self.max_lag_s,
@@ -128,7 +134,6 @@ class _LoopWatch:
         self._task.cancel()
         await asyncio.gather(self._task, return_exceptions=True)
         self._task = None
-        faulthandler.cancel_dump_traceback_later()
         gc.callbacks.remove(self._on_gc)
 
 
@@ -166,6 +171,7 @@ class Setup:
     device: dict
     peaks: Optional[dict]
     num_vertices: int
+    edge_shards: int
     src: object
     dst: object
     linked: object                 # ids of the vertices with an edge
@@ -182,15 +188,39 @@ class Setup:
     def kappa(self) -> int:
         return int(self.cfg["service"]["kappa"])
 
+    @property
+    def cache_capacity(self) -> int:
+        return int(self.cfg["service"]["cache_capacity"])
+
+
+def cell_mesh(svc_cfg: dict, chips: int, devices):
+    """The mesh a cell on ``chips`` devices registers its graph on, the axis
+    its edges are split over and that axis's size: ``(None, None, 1)`` on
+    one chip.  The configuration's ``service.mesh`` (``shape``, ``axes``,
+    optional ``shard_axis``) states it; by default one ``"shard"`` axis over
+    every chip, as the program's launchers build it.  Without
+    ``shard_axis`` the program splits over the first axis."""
+    if chips == 1:
+        return None, None, 1
+    from repro.launch.mesh import make_mesh
+
+    spec = svc_cfg.get("mesh", {"shape": [chips], "axes": ["shard"]})
+    mesh = make_mesh(spec["shape"], spec["axes"], devices=devices[:chips])
+    axis = spec.get("shard_axis")
+    return mesh, axis, int(mesh.shape[axis or mesh.axis_names[0]])
+
 
 def set_up(root: Path, workload: str, seed: int, *, rehearsal: bool = False,
-           graph: Optional[Dict] = None, precision=None, log=print) -> Setup:
+           graph: Optional[Dict] = None, service: Optional[Dict] = None,
+           precision=None, log=print) -> Setup:
     """Make the cell's graph from the seed and register it on a service
-    built from the configuration.  ``graph`` overrides generator parameters
-    and ``precision`` the traffic's (rehearsals and control readings)."""
+    built from the configuration.  ``graph`` overrides generator parameters,
+    ``service`` service settings and ``precision`` the traffic's
+    (rehearsals and control readings)."""
     man = Manifest(root)
     cell = man.workload(workload)
     cfg = man.config(cell["config"])
+    cfg = {**cfg, "service": {**cfg["service"], **(service or {})}}
     params = man.traffic(cell["traffic"])
 
     import jax
@@ -199,6 +229,9 @@ def set_up(root: Path, workload: str, seed: int, *, rehearsal: bool = False,
     from repro.ppr_serving import PPRService, precision_key
 
     if rehearsal:
+        if len(jax.devices()) < int(cell["chips"]):
+            raise NoChip(f"the cell asks for {cell['chips']} devices, JAX "
+                         f"found {len(jax.devices())}")
         device = {"platform": jax.devices()[0].platform,
                   "kind": jax.devices()[0].device_kind,
                   "count": len(jax.devices())}
@@ -220,6 +253,8 @@ def set_up(root: Path, workload: str, seed: int, *, rehearsal: bool = False,
     svc_cfg = cfg["service"]
     prec = params["precision"] if precision is None else precision
     pkey = precision_key(prec)
+    mesh, axis, shards = cell_mesh(svc_cfg, int(cell["chips"]),
+                                   jax.devices())
     svc = PPRService(kappa=int(svc_cfg["kappa"]),
                      iterations=int(svc_cfg["iterations"]),
                      alpha=float(svc_cfg["alpha"]),
@@ -228,13 +263,14 @@ def set_up(root: Path, workload: str, seed: int, *, rehearsal: bool = False,
     t = time.perf_counter()
     svc.register_graph(cell["config"], COOGraph.from_edges(src, dst, n),
                        formats=[] if pkey == "f32" else [prec],
-                       engine=svc_cfg["engine"])
+                       mesh=mesh, mesh_axis=axis, engine=svc_cfg["engine"])
     log(f"set-up: COOGraph built and registered in "
         f"{time.perf_counter() - t:.3f} s")
     return Setup(man=man, workload=workload, cell=cell, cfg=cfg,
                  params={**params, "precision": prec},
                  limits=man.limits(workload), device=device,
-                 peaks=peaks, num_vertices=n, src=src, dst=dst,
+                 peaks=peaks, num_vertices=n, edge_shards=shards,
+                 src=src, dst=dst,
                  linked=linked_vertices(n, src, dst), precision=prec, precision_key=pkey,
                  stated_precision=precision_key(params["precision"]),
                  svc=svc)
@@ -293,15 +329,18 @@ def reference_for(st: Setup):
 
 def run_cell(root: Path, workload: str, seed: int, seconds: float,
              trace: bool, *, rehearsal: bool = False,
-             graph: Optional[Dict] = None, precision=None,
+             graph: Optional[Dict] = None, service: Optional[Dict] = None,
+             precision=None,
              trace_dir: Optional[Path] = None,
              t_start: Optional[float] = None, log=print) -> Dict:
     """The result dict ``run.py`` prints; ``log`` takes the lines printed
-    before it.  ``graph`` and ``precision`` as ``set_up`` takes them."""
+    before it.  ``graph``, ``service`` and ``precision`` as ``set_up``
+    takes them."""
     t_start = time.perf_counter() if t_start is None else t_start
     st = set_up(root, workload, seed, rehearsal=rehearsal, graph=graph,
-                precision=precision, log=log)
-    sched = make_schedule(st.params, seed, st.linked, st.kappa, seconds)
+                service=service, precision=precision, log=log)
+    sched = make_schedule(st.params, seed, st.linked, st.kappa, seconds,
+                          st.cache_capacity)
     answer_in_setup(st, sched.warm, sched.k, "warm-up", log)
     answer_in_setup(st, sched.prefill, sched.k, "prefill", log)
     st.svc.telemetry.reset()
@@ -344,7 +383,8 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     ctx = Context(requests=reqs, in_window=out["in_window"],
                   latencies_s=out["latencies_s"], window_s=out["window_s"],
                   setup_s=out["setup_s"], num_vertices=st.num_vertices,
-                  num_edges=len(st.src), kappa=st.kappa,
+                  num_edges=len(st.src), edge_shards=st.edge_shards,
+                  kappa=st.kappa,
                   iterations=int(svc_cfg["iterations"]), registry=registry,
                   summary=summary, peaks=st.peaks)
     man = st.man
@@ -465,8 +505,21 @@ def _open_window(reqs: List[load.Request], t0: float) -> Dict:
 
 
 def rehearse(root: Path, workload: str, seed: int, seconds: float,
-             graph: Dict, precision=None, log=lambda _m: None) -> Dict:
+             graph: Optional[Dict] = None, precision=None,
+             log=lambda _m: None) -> Dict:
     """``run_cell`` on whatever backend JAX has, at the graph size ``graph``
-    sets, with no device check and no timing metric."""
+    sets (by default the cell's generator's ``REHEARSAL``), with no look for
+    a chip and no timing metric.  The result cache keeps the share of the
+    vertices it holds in the deployment, so a tiny graph's does not hold
+    them all.  A cell on more than one chip needs as many devices
+    (``repro.launch.mesh.cpu_host_devices`` on the CPU)."""
+    man = Manifest(root)
+    cfg = man.config(man.workload(workload)["config"])
+    gen = man.generator(cfg["graph"]["generator"])
+    graph = gen.REHEARSAL if graph is None else graph
+    share = gen.sizes({**cfg["graph"], **graph})[0] / gen.sizes(cfg["graph"])[0]
+    capacity = int(cfg["service"]["cache_capacity"])
     return run_cell(root, workload, seed, seconds, trace=False,
-                    rehearsal=True, graph=graph, precision=precision, log=log)
+                    rehearsal=True, graph=graph,
+                    service={"cache_capacity": max(1, round(capacity * share))},
+                    precision=precision, log=log)

@@ -3,9 +3,17 @@
 Layout under the benchmark directory (``bench/``):
 
     configs/<config>.json        the deployment: graph generator and sizes,
-                                 service and admission settings (the file
-                                 the manifest's configuration entry names)
-    generators/<generator>.py    ``generate(params, seed) -> (V, src, dst)``
+                                 service and admission settings, and for a
+                                 cell on more than one chip optionally the
+                                 mesh (``service.mesh``: ``shape``, ``axes``,
+                                 ``shard_axis``); the file the manifest's
+                                 configuration entry names
+    generators/<generator>.py    ``generate(params, seed) -> (V, src, dst)``;
+                                 ``REHEARSAL``, the parameters a CPU
+                                 rehearsal overrides to make a tiny graph;
+                                 ``sizes(params) -> (V, arcs bound)`` and,
+                                 where it runs on the device,
+                                 ``device_program(params)`` (compile_check)
     traffic/<traffic>.json       parameters of the one traffic generator
     limits/<workload>.json       the correctness limits of one cell
     metrics/<metric>.py          ``read(ctx) -> float | None`` for one metric
@@ -17,6 +25,7 @@ from __future__ import annotations
 
 import importlib.util
 import json
+import math
 import re
 from pathlib import Path
 from typing import Dict, List
@@ -26,9 +35,16 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES_E2E = {"host_clock", "device_trace"}
 SETUP = "setup_s"
+CHIPS = (1, 4)          # the machines a cell can ask for
+MAX_CELLS = 24
+MAX_TEXT = 200          # a why, a layer or a source: one line of this many
 
 
-def _load_module(path: Path):
+def _text_ok(text: str) -> bool:
+    return 1 <= len(text) <= MAX_TEXT and not any(c in text for c in "\n\r\t")
+
+
+def load_module(path: Path):
     spec = importlib.util.spec_from_file_location(
         "bench_file_" + re.sub(r"\W", "_", path.stem), path)
     if spec is None or not path.is_file():
@@ -68,10 +84,10 @@ class Manifest:
                           .read_text())
 
     def generator(self, name: str):
-        return _load_module(self.dir / "generators" / f"{name}.py")
+        return load_module(self.dir / "generators" / f"{name}.py")
 
     def reader(self, metric: str):
-        return _load_module(self.dir / "metrics" / f"{metric}.py")
+        return load_module(self.dir / "metrics" / f"{metric}.py")
 
     # ---- which metrics a cell reports -------------------------------------
     def end_to_end(self, workload: str) -> List[dict]:
@@ -111,15 +127,54 @@ class Manifest:
         if len(d["end_to_end"]) > 5:
             out.append("more than 4 end-to-end metrics besides setup_s")
         configs = {c["name"]: c for c in d["configs"]}
+        meshes = {}
         for c in configs.values():
-            if not (self.root / c["file"]).is_file():
-                out.append(f"config {c['name']}: no file {c['file']}")
             out += [f"bad reduced key {k!r}" for k in c["reduced"]
                     if not NAME.match(k)]
-        for w in d["workloads"]:
+            out += [f"config {c['name']}: {key} is not one line of 1 to "
+                    f"{MAX_TEXT} characters" for key in ("source", "why")
+                    if not _text_ok(c[key])]
+            if not any(w["config"] == c["name"] for w in d["workloads"]):
+                out.append(f"config {c['name']}: no cell uses it")
+            if not (self.root / c["file"]).is_file():
+                out.append(f"config {c['name']}: no file {c['file']}")
+                continue
+            cfg = self.config(c["name"])
+            if not (cfg.get("assumed") and cfg.get("guarantees")):
+                out.append(f"config {c['name']}: states no assumed sizes or "
+                           f"no guarantees")
+            if cfg["admission"]["kappa_max"] != cfg["service"]["kappa"]:
+                out.append(f"config {c['name']}: admission kappa_max is not "
+                           f"the service's kappa")
+            mesh = cfg["service"].get("mesh")
+            if mesh is not None:
+                meshes[c["name"]] = mesh
+                if len(mesh["shape"]) != len(mesh["axes"]) or \
+                        mesh.get("shard_axis", mesh["axes"][0]) \
+                        not in mesh["axes"]:
+                    out.append(f"config {c['name']}: mesh shape, axes and "
+                               f"shard_axis disagree")
+        cells = d["workloads"]
+        if len(cells) > MAX_CELLS:
+            out.append(f"{len(cells)} cells, more than {MAX_CELLS}")
+        four = sum(1 for w in cells if w["chips"] == 4)
+        if four > max(1, len(cells) // 2):
+            out.append(f"{four} of {len(cells)} cells on 4 chips, more than "
+                       f"{max(1, len(cells) // 2)}")
+        for w in cells:
             name = w["name"]
+            if w["chips"] not in CHIPS:
+                out.append(f"{name}: chips {w['chips']!r}, not one of {CHIPS}")
+            if not _text_ok(w["why"]):
+                out.append(f"{name}: why is not one line of 1 to {MAX_TEXT} "
+                           f"characters")
             if w["config"] not in configs:
                 out.append(f"{name}: unknown config {w['config']!r}")
+            mesh = meshes.get(w["config"])
+            if mesh is not None and math.prod(mesh["shape"]) != w["chips"]:
+                out.append(f"{name}: the mesh of {w['config']} spans "
+                           f"{math.prod(mesh['shape'])} chips, not "
+                           f"{w['chips']}")
             for sub, fname in (("traffic", w["traffic"]), ("limits", name)):
                 if not (self.dir / sub / f"{fname}.json").is_file():
                     out.append(f"{name}: no {sub}/{fname}.json")
@@ -130,6 +185,9 @@ class Manifest:
             if not self.per_layer(name):
                 out.append(f"{name}: reports no per-layer metric")
         for m in d["per_layer"]:
+            if not _text_ok(m["layer"]):
+                out.append(f"{m['name']}: layer is not one line of 1 to "
+                           f"{MAX_TEXT} characters")
             if m["moves"] not in metrics or m["moves"] in \
                     {p["name"] for p in d["per_layer"]}:
                 out.append(f"{m['name']}: moves {m['moves']!r}, which is no "

@@ -21,13 +21,15 @@ def roofline_pct(least_bytes: float, seconds: float,
 
 def iteration_roofline_pct(ctx):
     """The eq. (1) iteration's roofline share in a traced window: least
-    bytes of one iteration over peak HBM bandwidth, divided by the device
-    time per iteration, which is the device time of every program the
-    waves ran except top-K over (waves x iterations).  None where the trace
-    saw no whole wave."""
+    bytes of one iteration over the peak HBM bandwidth of the devices its
+    edges are split over (``ctx.edge_shards``), divided by the device time
+    per iteration, which is the device time of every program the waves ran
+    except top-K over (waves x iterations), both averaged over the devices
+    traced.  None where the trace saw no whole wave."""
     s = ctx.summary
     if s is None or s.waves == 0 or s.iteration_s <= 0 or ctx.peaks is None:
         return None
     per_iteration = s.iteration_s / (s.waves * ctx.iterations)
     least = iteration_bytes(ctx.num_vertices, ctx.num_edges, ctx.kappa)
-    return roofline_pct(least, per_iteration, ctx.peaks["hbm_bytes_per_s"])
+    return roofline_pct(least, per_iteration,
+                        ctx.peaks["hbm_bytes_per_s"] * ctx.edge_shards)

@@ -1,15 +1,13 @@
 """A run with the timed path broken underneath comes out not correct, once
-for each fault the cells can have (one chip: no exchange between chips to
-leave out).  The harness runs as on the chip, less its look for one, at a
-tiny size, against the cells' own limits."""
-from pathlib import Path
-
+for each fault the cells can have.  The faults are planted in the
+single-device engine, so every one-chip cell that it serves is tried (no
+exchange between chips to leave out).  The harness runs as on the chip,
+less its look for one, at the generator's tiny size, against the cells' own
+limits."""
 import pytest
 
 from bench.harness import rehearse
-
-REPO = Path(__file__).resolve().parents[2]
-TINY = {"kron20": {"scale": 9}, "pl2e5": {"num_vertices": 3000}}
+from bench.tests.cells import REPO, cells_where
 
 
 def step_returns_state(monkeypatch):
@@ -59,16 +57,14 @@ def answer_altered(monkeypatch):
 
 @pytest.mark.parametrize("fault", [step_returns_state,
                                    half_the_batch_left_out, answer_altered])
-@pytest.mark.parametrize("workload", ["kron20.q25.backlog", "pl2e5.q25.open",
-                                      "kron20.f32.backlog"])
+@pytest.mark.parametrize("workload", cells_where(chips=1, engine="single"))
 def test_fault_makes_the_run_not_correct(monkeypatch, root_of, fault,
                                          workload):
     fault(monkeypatch)
-    r = rehearse(root_of(workload), workload, 2**31 + 21, 1.0,
-                 TINY[workload.split(".")[0]])
+    r = rehearse(root_of(workload), workload, 2**31 + 21, 1.0)
     assert not r["correct"], r["checks"]
 
 
 def test_the_same_run_unbroken_is_correct():
-    r = rehearse(REPO, "kron20.q25.backlog", 2**31 + 21, 2.0, TINY["kron20"])
+    r = rehearse(REPO, "kron20.q25.backlog", 2**31 + 21, 2.0)
     assert r["correct"], r["checks"]

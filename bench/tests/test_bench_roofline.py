@@ -23,13 +23,28 @@ def test_iteration_roofline_pct_reads_the_trace_summary():
 
     class Ctx:
         summary, iterations, peaks = S, 10, {"hbm_bytes_per_s": 819e9}
-        num_vertices, num_edges, kappa = 1000, 10_000, 16
+        num_vertices, num_edges, edge_shards, kappa = 1000, 10_000, 1, 16
 
     least = 12 * 10_000 + 8 * 1000 * 16
     assert roofline.iteration_roofline_pct(Ctx) == \
         pytest.approx(100 * least / 819e9 / 1e-3)
     Ctx.summary = None
     assert roofline.iteration_roofline_pct(Ctx) is None
+
+
+def test_iteration_roofline_pct_of_a_split_stream_counts_every_shard():
+    # four devices each stream a quarter of the edges in the same device
+    # time per iteration as one device streaming all: a quarter of the share
+    class S:
+        waves, iteration_s = 2, 2 * 10 * 1e-3
+
+    class Ctx:
+        summary, iterations, peaks = S, 10, {"hbm_bytes_per_s": 819e9}
+        num_vertices, num_edges, edge_shards, kappa = 1000, 10_000, 1, 16
+
+    one = roofline.iteration_roofline_pct(Ctx)
+    Ctx.edge_shards = 4
+    assert roofline.iteration_roofline_pct(Ctx) == pytest.approx(one / 4)
 
 
 def test_peaks_of_v5e_cite_their_source():

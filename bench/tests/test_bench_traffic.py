@@ -1,12 +1,15 @@
 """The one traffic generator."""
-import numpy as np
+from collections import OrderedDict
 
-from bench.traffic import make_schedule
+import numpy as np
+import pytest
+
+from bench.traffic import _lru_steady, _zipf_inverse_cdf, make_schedule, rng
 
 OPEN = {"loop": "open", "rate_qps": 40.0, "arrivals": "poisson",
         "schedule_seed": 0, "clients": 8,
         "vertices": "uniform", "precision": "Q1.25", "k": 10}
-ZIPF = {**OPEN, "vertices": "zipf", "zipf_s": 0.99, "prefill_hot": 64}
+ZIPF = {**OPEN, "vertices": "zipf", "zipf_s": 0.99, "prefill": "lru_steady"}
 ALL = np.arange(10_000)
 CLOSED = {"loop": "closed", "clients": 32, "max_requests": 4096,
           "vertices": "uniform", "precision": "f32", "k": 10}
@@ -32,18 +35,39 @@ def test_uniform_vertices_are_distinct_and_warm_up_is_apart():
 
 
 def test_same_seed_same_schedule():
-    a, b = (make_schedule(ZIPF, 9, ALL, 16, 10.0) for _ in range(2))
+    a, b = (make_schedule(ZIPF, 9, ALL, 16, 10.0, 64) for _ in range(2))
     assert np.array_equal(a.vertices, b.vertices)
     assert np.array_equal(a.due_s, b.due_s)
+    assert np.array_equal(a.prefill, b.prefill)
 
 
 def test_zipf_prefills_the_hottest_and_repeats_them():
-    s = make_schedule(ZIPF, 4, ALL, 16, 20.0)
+    s = make_schedule(ZIPF, 4, ALL, 16, 20.0, 256)
     hot = set(s.prefill.tolist())
-    assert len(hot) == 64 and len(s.warm) == 0
+    assert len(hot) == len(s.prefill) == 256 and len(s.warm) == 0
     share = np.mean([v in hot for v in s.vertices.tolist()])
     assert 0.4 < share < 0.8
     assert len(set(s.vertices.tolist())) < 0.7 * len(s.vertices)
+    # the hottest vertex, the window's most asked, is among them
+    values, counts = np.unique(s.vertices, return_counts=True)
+    assert values[np.argmax(counts)] in hot
+
+
+def test_lru_steady_is_what_an_lru_holds_after_the_stream():
+    law = _zipf_inverse_cdf(5000, 0.99)
+    held = _lru_steady(law, 100, rng(0, 4))
+    lru = OrderedDict()
+    for v in law(rng(0, 4).random(64 * 100)).tolist():
+        lru[v] = None
+        lru.move_to_end(v)
+        if len(lru) > 100:
+            lru.popitem(last=False)
+    assert held.tolist() == list(lru)       # least recent first
+
+
+def test_zipf_with_an_unknown_prefill_is_refused():
+    with pytest.raises(ValueError, match="prefill"):
+        make_schedule({**ZIPF, "prefill": "hottest"}, 1, ALL, 16, 5.0, 64)
 
 
 def test_closed_loop_draws_distinct_vertices_for_its_clients():

@@ -25,8 +25,13 @@ A traffic file (``traffic/<name>.json``) holds only parameters:
                   draws its search keys: an isolated vertex's answer is a
                   tie of every other vertex.
     precision     the wire ``precision`` of every request; ``k`` its size.
-    prefill_hot   (zipf) the hottest vertices answered in set-up, which fills
-                  the result cache before the window.
+    prefill       (zipf) "lru_steady": set-up answers what an LRU result
+                  cache of the configuration's ``cache_capacity`` holds after
+                  serving this law for long: the last ``cache_capacity``
+                  distinct vertices of a stream drawn from it (from
+                  ``schedule_seed``, so every seed holds the same ranks),
+                  least recent first, so the window starts in the state a
+                  deployment that runs on keeps.
 """
 from __future__ import annotations
 
@@ -37,7 +42,7 @@ import numpy as np
 
 # independent random streams drawn from one seed (the graph generators
 # take the run seed itself)
-ORDER_STREAM, GAPS_STREAM, CHECK_STREAM = 1, 2, 3
+ORDER_STREAM, GAPS_STREAM, CHECK_STREAM, PREFILL_STREAM = 1, 2, 3, 4
 
 
 def rng(seed: int, stream: int) -> np.random.Generator:
@@ -72,10 +77,20 @@ def _zipf_inverse_cdf(num_vertices: int, s: float):
     return inv
 
 
+def _lru_steady(inverse_cdf, capacity: int, stream_rng) -> np.ndarray:
+    """What an LRU of ``capacity`` holds after a long stream drawn through
+    ``inverse_cdf``: the stream's last ``capacity`` distinct values, least
+    recent first (fewer where the stream holds fewer)."""
+    newest_first = inverse_cdf(stream_rng.random(64 * capacity))[::-1]
+    values, first = np.unique(newest_first, return_index=True)
+    return values[np.argsort(first)][:capacity][::-1]
+
+
 def make_schedule(params: dict, seed: int, linked: np.ndarray, kappa: int,
-                  seconds: float) -> Schedule:
+                  seconds: float, cache_capacity: int = 0) -> Schedule:
     """The schedule of one run; ``linked`` holds the ids of the graph's
-    vertices that have an edge, the only ones asked for."""
+    vertices that have an edge, the only ones asked for, and
+    ``cache_capacity`` is the service's result cache (``prefill``)."""
     loop = params["loop"]
     order = rng(seed, ORDER_STREAM)
     k = int(params.get("k", 10))
@@ -104,13 +119,16 @@ def make_schedule(params: dict, seed: int, linked: np.ndarray, kappa: int,
         vertices, warm = perm[:n], perm[n:n + kappa]
     elif kind == "zipf":
         popularity = order.permutation(num_vertices)
-        ranks = _stratified(n, _zipf_inverse_cdf(num_vertices,
-                                                 float(params["zipf_s"])),
-                            order)
-        vertices = popularity[ranks]
-        hot = int(params.get("prefill_hot", 0))
-        prefill = popularity[:hot]       # also compiles the wave shapes
-        warm = popularity[:0] if hot else popularity[-kappa:]
+        law = _zipf_inverse_cdf(num_vertices, float(params["zipf_s"]))
+        vertices = popularity[_stratified(n, law, order)]
+        fill = params.get("prefill")
+        if fill == "lru_steady":         # also compiles the wave shapes
+            prefill = popularity[_lru_steady(
+                law, int(cache_capacity),
+                rng(int(params["schedule_seed"]), PREFILL_STREAM))]
+        elif fill is not None:
+            raise ValueError(f"unknown prefill {fill!r}")
+        warm = popularity[:0] if len(prefill) else popularity[-kappa:]
     else:
         raise ValueError(f"unknown vertices {kind!r}")
     linked = np.asarray(linked, np.int64)
